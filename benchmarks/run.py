@@ -12,6 +12,7 @@ import sys
 import time
 
 from benchmarks.common import Reporter
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = [
     ("fig2_scaling_gap", "benchmarks.scaling_gap"),
@@ -30,6 +31,7 @@ def main() -> None:
                     help="substring filter, e.g. fig11")
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

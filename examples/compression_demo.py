@@ -16,12 +16,14 @@ from benchmarks.common import make_group, model  # noqa: E402
 from repro.core.collector import KVCollector
 from repro.core.diff_store import build_round_family, compression_stats
 from repro.core.restore import dense_restore, fused_restore_paged, dense_restore_paged
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--agents", type=int, default=6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg, params = model("qwen2.5-7b")
     g = make_group(cfg, params, args.agents, priv_len=32, block_len=128,

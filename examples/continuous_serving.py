@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.core.rounds import SubsetGather, generate_trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import ContinuousEngine, ServingEngine, get_policy
 
@@ -36,6 +37,7 @@ def main() -> None:
     ap.add_argument("--stream", action="store_true",
                     help="print tokens at the tick they are produced")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config("qwen2.5-7b").replace(dtype="float32")
     params = init_params(jax.random.PRNGKey(0), cfg)

@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import get_smoke_config
 from repro.core.rounds import AllGather, SubsetGather, generate_trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import MODES, ServingEngine, get_policy
 
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--topology", default="allgather",
                     help="allgather | grouped:<size> | ring:<k>")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config("qwen2.5-7b").replace(dtype="float32")
     params = init_params(jax.random.PRNGKey(0), cfg)

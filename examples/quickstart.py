@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_smoke_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import decode_step, init_params, prefill
 
 
@@ -20,6 +21,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch).replace(dtype="float32")
     print(f"arch={cfg.name} ({cfg.arch_type}) layers={cfg.n_layers} "

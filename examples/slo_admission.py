@@ -18,6 +18,7 @@ import jax
 
 from repro.configs import get_smoke_config
 from repro.core.rounds import generate_trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import (
     RoundPlanner,
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--slo-factor", type=float, default=1.5,
                     help="SLO as a multiple of the calibration round")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config("qwen2.5-7b").replace(dtype="float32")
     params = init_params(jax.random.PRNGKey(0), cfg)

@@ -9,6 +9,7 @@ import os
 import jax
 
 from repro.configs import get_smoke_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.training import AdamWConfig, DataConfig, SyntheticTokens, train
 from repro.training.checkpoint import save
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--out", default="/tmp/repro_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch).replace(dtype="float32")
     n_params = cfg.param_count()

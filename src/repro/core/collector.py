@@ -54,6 +54,7 @@ class ReusePlan:
 class CollectiveResult:
     plan: ReusePlan
     pic: PICResult               # batched over the group
+    priv_mode: str = "none"      # the _runner path that consumed ``priv``
 
 
 @dataclass
@@ -398,7 +399,7 @@ class KVCollector:
         plan = ReusePlan(list(request_ids), master,
                          np.asarray(res.sel_idx[0]), dev, S, n_sel,
                          sel_idx_all=np.asarray(res.sel_idx))
-        return CollectiveResult(plan, res)
+        return CollectiveResult(plan, res, priv_mode)
 
     # ------------------------------------------------------------------
     def serial_reuse(

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(m_ref, x_ref, o_ref):
@@ -18,13 +19,15 @@ def _kernel(m_ref, x_ref, o_ref):
     d = jnp.abs(x_ref[0, 0].astype(jnp.float32) - m_ref[0, 0].astype(jnp.float32))
     cur = jnp.max(d)
 
+    b = pl.program_id(0)
+
     @pl.when(l == 0)
     def _init():
-        o_ref[0, 0] = cur
+        o_ref[b] = cur
 
     @pl.when(l > 0)
     def _acc():
-        o_ref[0, 0] = jnp.maximum(o_ref[0, 0], cur)
+        o_ref[b] = jnp.maximum(o_ref[b], cur)
 
 
 def block_diff_kernel(
@@ -40,12 +43,13 @@ def block_diff_kernel(
     mb = master.reshape(L, nb, bt, KV, hd)
     xb = mirror.reshape(L, nb, bt, KV, hd)
     spec = pl.BlockSpec((1, 1, bt, KV, hd), lambda b, l: (l, b, 0, 0, 0))
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _kernel,
         grid=(nb, L),
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, 1), lambda b, l: (0, b)),
-        out_shape=jax.ShapeDtypeStruct((1, nb), jnp.float32),
+        # one scalar per block: the whole [nb] vector lives in SMEM for the
+        # launch (a (1, 1) VMEM block is below the (8, 128) tile)
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((nb,), jnp.float32),
         interpret=interpret,
     )(mb, xb)
-    return out[0]

@@ -41,16 +41,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _rope_delta(k: jax.Array, delta: jax.Array, theta: float) -> jax.Array:
-    """Rotate keys [bt, KV, hd] by per-token position delta [bt].
+    """Rotate keys [bt, KV, hd] by per-token position delta [bt, 1].
 
     Frequencies use the same ``theta ** (i/half)`` form as the jnp oracle
     (ref.rope_delta_ref) so interpret-mode runs are bit-identical to it.
     """
     bt, KV, hd = k.shape
     half = hd // 2
-    exps = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1) / half
+    # Mosaic's iota is integer-only: count in int32, then cast
+    exps = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(
+        jnp.float32) / half
     freqs = 1.0 / (theta ** exps)                        # [1, half]
-    ang = delta.astype(jnp.float32)[:, None] * freqs     # [bt, half]
+    ang = delta.astype(jnp.float32) * freqs              # [bt, half]
     cos = jnp.cos(ang)[:, None, :]
     sin = jnp.sin(ang)[:, None, :]
     kf = k.astype(jnp.float32)
@@ -102,7 +104,9 @@ def fused_diff_restore_kernel(
     spec_diff = pl.BlockSpec(
         (1, 1, bt, KV, hd),
         lambda l, b, ds, sm: (l, jnp.maximum(ds[b], 0), 0, 0, 0))
-    spec_dp = pl.BlockSpec((1, bt), lambda l, b, ds, sm: (b, 0))
+    # delta as [nb, bt, 1] columns: a (bt, 1) block is whole in its last
+    # dim and keeps the tokens on sublanes, beside the K rows they rotate
+    spec_dp = pl.BlockSpec((1, bt, 1), lambda l, b, ds, sm: (b, 0, 0))
     spec_out = pl.BlockSpec(
         (1, 1, bt, KV, hd), lambda l, b, ds, sm: (l, sm[b], 0, 0, 0))
 
@@ -122,7 +126,7 @@ def fused_diff_restore_kernel(
         interpret=interpret,
     )
     return fn(diff_slot, slot_map, master_k, master_v, diff_k, diff_v,
-              delta_pos, pool_k, pool_v)
+              delta_pos.reshape(nb, bt, 1), pool_k, pool_v)
 
 
 def _family_kernel(diff_slot_ref, slot_map_ref,   # scalar prefetch [M, nb]
@@ -176,7 +180,8 @@ def fused_family_restore_kernel(
     spec_diff = pl.BlockSpec(
         (1, 1, 1, bt, KV, hd),
         lambda l, b, m, ds, sm: (m, l, jnp.maximum(ds[m, b], 0), 0, 0, 0))
-    spec_dp = pl.BlockSpec((1, 1, bt), lambda l, b, m, ds, sm: (m, b, 0))
+    spec_dp = pl.BlockSpec(
+        (1, 1, bt, 1), lambda l, b, m, ds, sm: (m, b, 0, 0))
     spec_out = pl.BlockSpec(
         (1, 1, bt, KV, hd), lambda l, b, m, ds, sm: (l, sm[m, b], 0, 0, 0))
 
@@ -196,4 +201,4 @@ def fused_family_restore_kernel(
         interpret=interpret,
     )
     return fn(diff_slot, slot_map, master_k, master_v, diff_k, diff_v,
-              delta_pos, pool_k, pool_v)
+              delta_pos.reshape(M, nb, bt, 1), pool_k, pool_v)

@@ -18,9 +18,11 @@ dense and paged paths lives in that one function):
 The single query always sits at position ``skv - 1`` — the just-written
 token attends over everything before it — so causality is carried
 entirely by the validity mask ``cols < skv``; there is no per-row causal
-triangle. The q operand arrives padded to the f32 sublane tile (8 rows,
-all copies of the one query) from the ops wrapper, which slices row 0
-back out; padded KV tiles past ``skv`` are fully masked and contribute
+triangle. The q operand arrives zero-padded to the f32 sublane tile from
+the ops wrapper — the dense kernel takes one head's query as row 0 of 8,
+the paged kernel one KV head's G query heads as its rows — and the
+wrapper slices the padding back off; padded KV tiles past ``skv`` are
+fully masked and contribute
 exact zeros to the online softmax, so dense and paged runs stay
 bit-identical even when their tile counts differ.
 """
@@ -123,7 +125,7 @@ def flash_decode_kernel(
 def _paged_decode_kernel(pidx_ref, q_ref, pk_ref, pv_ref, tk_ref, tv_ref,
                          o_ref, m_scr, l_scr, acc_scr, *,
                          scale, window, bq, bt, nbh, span_len, skv):
-    j = pl.program_id(1)
+    j = pl.program_id(0)
     is_page = j < nbh
     # dense-equivalent position of this tile's first KV token: page tiles
     # sit at j*bt, tail tiles start right after the (possibly ragged) span
@@ -137,28 +139,29 @@ def _paged_decode_kernel(pidx_ref, q_ref, pk_ref, pv_ref, tk_ref, tv_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale            # [bq, hd]
-        k_page = pk_ref[0, :, 0, :].astype(jnp.float32)     # [bt, hd]
-        v_page = pv_ref[0, :, 0, :].astype(jnp.float32)
-        k_tail = tk_ref[:, 0, :].astype(jnp.float32)        # [bt, hd]
-        v_tail = tv_ref[:, 0, :].astype(jnp.float32)
-        k = jnp.where(is_page, k_page, k_tail)
-        v = jnp.where(is_page, v_page, v_tail)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bq, bt]
+        k_all = jnp.where(is_page, pk_ref[0].astype(jnp.float32),
+                          tk_ref[...].astype(jnp.float32))  # [bt, KV, hd]
+        v_all = jnp.where(is_page, pv_ref[0].astype(jnp.float32),
+                          tv_ref[...].astype(jnp.float32))
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bt), 1)
         # a ragged last page carries slots past span_len; padded tail rows
         # sit past skv — both are masked out, never re-laid-out
         mask = cols < jnp.where(is_page, span_len, skv)
         if window:
             mask &= (qpos - cols) < window
-        s = jnp.where(mask, s, NEG_INF)
-        _softmax_update(s, v, o_ref, m_scr, l_scr, acc_scr)
+        # each KV head's query heads are the rows of one [bq, hd] tile
+        for g in range(k_all.shape[1]):
+            q = q_ref[g].astype(jnp.float32) * scale        # [bq, hd]
+            s = jax.lax.dot_general(
+                q, k_all[:, g, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [bq, bt]
+            s = jnp.where(mask, s, NEG_INF)
+            _softmax_update(s, v_all[:, g, :], o_ref.at[pl.ds(g, 1)],
+                            m_scr.at[g], l_scr.at[g], acc_scr.at[g])
 
 
 def flash_decode_paged_kernel(
-    q: jax.Array,          # [H, Bq, hd] — Bq rows all carry the one query
+    q: jax.Array,          # [KV, Bq, hd] — row r of KV head g is query head g*G+r
     pool_k: jax.Array,     # [P, bt, KV, hd] round page pool (one layer)
     pool_v: jax.Array,
     page_idx: jax.Array,   # int32 [nbh] — KV tile j lives in pool[page_idx[j]]
@@ -184,10 +187,15 @@ def flash_decode_paged_kernel(
     scalar-prefetch operand, so each KV tile's HBM→VMEM copy is issued
     straight against ``pool[page_idx[j]]`` (the tail rides as trailing
     tiles). The query sits at position ``span_len + tail_len - 1``.
+
+    The grid is ``(nk,)``: a page tile carries every KV head, so each
+    page crosses HBM→VMEM exactly once per step, and the G query heads
+    of a KV head ride as the rows of one ``[Bq, hd]`` tile (``Bq`` is G
+    padded to the sublane minimum; see ``ops.flash_decode_paged``).
     """
-    H, Bq, hd = q.shape
-    P, bt, KV, _ = pool_k.shape
-    G = H // KV
+    KV, Bq, hd = q.shape
+    P, bt, _, _ = pool_k.shape
+    assert pool_k.shape[2] == KV, (pool_k.shape, q.shape)
     nbh = int(page_idx.shape[0])
     assert span_len > 0 and nbh == -(-span_len // bt), (span_len, bt, nbh)
     assert tail_k.shape[0] % bt == 0 and tail_k.shape[0] >= tail_len
@@ -200,37 +208,37 @@ def flash_decode_paged_kernel(
         _paged_decode_kernel, scale=scale, window=window,
         bq=Bq, bt=bt, nbh=nbh, span_len=span_len, skv=skv)
 
-    def qmap(h, j, pidx):
-        return (h, 0, 0)
+    def qmap(j, pidx):
+        return (0, 0, 0)
 
-    def pmap(h, j, pidx):
+    def pmap(j, pidx):
         # page tiles resolve through the prefetched table; clamped for
         # tail steps (the fetched page is ignored there)
-        return (pidx[jnp.minimum(j, nbh - 1)], 0, h // G, 0)
+        return (pidx[jnp.minimum(j, nbh - 1)], 0, 0, 0)
 
-    def tmap(h, j, pidx):
-        return (jnp.clip(j - nbh, 0, max(nt - 1, 0)), h // G, 0)
+    def tmap(j, pidx):
+        return (jnp.clip(j - nbh, 0, max(nt - 1, 0)), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(H, nk),
+        grid=(nk,),
         in_specs=[
-            pl.BlockSpec((1, Bq, hd), qmap),
-            pl.BlockSpec((1, bt, 1, hd), pmap),
-            pl.BlockSpec((1, bt, 1, hd), pmap),
-            pl.BlockSpec((bt, 1, hd), tmap),
-            pl.BlockSpec((bt, 1, hd), tmap),
+            pl.BlockSpec((KV, Bq, hd), qmap),
+            pl.BlockSpec((1, bt, KV, hd), pmap),
+            pl.BlockSpec((1, bt, KV, hd), pmap),
+            pl.BlockSpec((bt, KV, hd), tmap),
+            pl.BlockSpec((bt, KV, hd), tmap),
         ],
-        out_specs=pl.BlockSpec((1, Bq, hd), qmap),
+        out_specs=pl.BlockSpec((KV, Bq, hd), qmap),
         scratch_shapes=[
-            pltpu.VMEM((Bq, LANES), jnp.float32),
-            pltpu.VMEM((Bq, LANES), jnp.float32),
-            pltpu.VMEM((Bq, hd), jnp.float32),
+            pltpu.VMEM((KV, Bq, LANES), jnp.float32),
+            pltpu.VMEM((KV, Bq, LANES), jnp.float32),
+            pltpu.VMEM((KV, Bq, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((H, Bq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((KV, Bq, hd), q.dtype),
         interpret=interpret,
     )(page_idx, q, pool_k, pool_v, tail_k, tail_v)

@@ -149,10 +149,24 @@ def flash_prefill_kernel(
 # --------------------------------------------------------------------------
 # paged variant: KV tiles resolved through a page table
 # --------------------------------------------------------------------------
+def kv_head(tile: jax.Array, g) -> jax.Array:
+    """KV head ``g`` of a ``[bt, KV, hd]`` tile, as ``[bt, hd]``.
+
+    A page tile carries every KV head: Mosaic cannot block a single head
+    out of the second-minor axis, so the whole page is fetched and the
+    head picked in registers. ``g`` may be traced (a grid index); the
+    select chain keeps every slice static.
+    """
+    out = tile[:, 0, :]
+    for i in range(1, tile.shape[1]):
+        out = jnp.where(g == i, tile[:, i, :], out)
+    return out
+
+
 def _paged_kernel(pidx_ref, q_ref, pk_ref, pv_ref, tk_ref, tv_ref, o_ref,
                   m_scr, l_scr, acc_scr, *,
                   scale, causal, window, bq, bt, nbh, span_len, skv):
-    i, j = pl.program_id(1), pl.program_id(2)
+    kvh, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     row0 = i * bq
     is_page = j < nbh
     # dense-equivalent position of this tile's first KV token: page tiles
@@ -168,16 +182,11 @@ def _paged_kernel(pidx_ref, q_ref, pk_ref, pv_ref, tk_ref, tv_ref, o_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale            # [bq, hd]
-        k_page = pk_ref[0, :, 0, :].astype(jnp.float32)     # [bt, hd]
-        v_page = pv_ref[0, :, 0, :].astype(jnp.float32)
-        k_tail = tk_ref[:, 0, :].astype(jnp.float32)        # [bt, hd]
-        v_tail = tv_ref[:, 0, :].astype(jnp.float32)
-        k = jnp.where(is_page, k_page, k_tail)
-        v = jnp.where(is_page, v_page, v_tail)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bq, bt]
+        k_all = jnp.where(is_page, pk_ref[0].astype(jnp.float32),
+                          tk_ref[...].astype(jnp.float32))  # [bt, KV, hd]
+        v_all = jnp.where(is_page, pv_ref[0].astype(jnp.float32),
+                          tv_ref[...].astype(jnp.float32))
+        k, v = kv_head(k_all, kvh), kv_head(v_all, kvh)     # [bt, hd]
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bt), 0)
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bt), 1)
         # a ragged last page carries slots past span_len; padded tail rows
@@ -187,8 +196,15 @@ def _paged_kernel(pidx_ref, q_ref, pk_ref, pv_ref, tk_ref, tv_ref, o_ref,
             mask &= cols <= rows
         if window:
             mask &= (rows - cols) < window
-        s = jnp.where(mask, s, NEG_INF)
-        _softmax_update(s, v, o_ref, m_scr, l_scr, acc_scr)
+        # the G query heads sharing this KV head, one [bq, hd] tile each
+        for g in range(q_ref.shape[0]):
+            q = q_ref[g].astype(jnp.float32) * scale        # [bq, hd]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [bq, bt]
+            s = jnp.where(mask, s, NEG_INF)
+            _softmax_update(s, v, o_ref.at[pl.ds(g, 1)], m_scr.at[g],
+                            l_scr.at[g], acc_scr.at[g])
 
 
 def flash_prefill_paged_kernel(
@@ -222,6 +238,10 @@ def flash_prefill_paged_kernel(
     tiles). The q length must cover the full KV span
     (``Sq >= span_len + tail_len``, padded rows are sliced by the
     caller — see ``ops.flash_prefill_paged``).
+
+    The grid is ``(KV, nq, nk)``: one step serves the G query heads of one
+    KV head, so a page crosses HBM→VMEM once per KV head and q tile, not
+    once per query head.
     """
     H, Sq, hd = q.shape
     P, bt, KV, _ = pool_k.shape
@@ -241,32 +261,32 @@ def flash_prefill_paged_kernel(
         _paged_kernel, scale=scale, causal=causal, window=window,
         bq=bq, bt=bt, nbh=nbh, span_len=span_len, skv=skv)
 
-    def qmap(h, i, j, pidx):
-        return (h, i, 0)
+    def qmap(kvh, i, j, pidx):
+        return (kvh, i, 0)
 
-    def pmap(h, i, j, pidx):
+    def pmap(kvh, i, j, pidx):
         # page tiles resolve through the prefetched table; clamped for
         # tail steps (the fetched page is ignored there)
-        return (pidx[jnp.minimum(j, nbh - 1)], 0, h // G, 0)
+        return (pidx[jnp.minimum(j, nbh - 1)], 0, 0, 0)
 
-    def tmap(h, i, j, pidx):
-        return (jnp.clip(j - nbh, 0, max(nt - 1, 0)), h // G, 0)
+    def tmap(kvh, i, j, pidx):
+        return (jnp.clip(j - nbh, 0, max(nt - 1, 0)), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(H, nq, nk),
+        grid=(KV, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, hd), qmap),
-            pl.BlockSpec((1, bt, 1, hd), pmap),
-            pl.BlockSpec((1, bt, 1, hd), pmap),
-            pl.BlockSpec((bt, 1, hd), tmap),
-            pl.BlockSpec((bt, 1, hd), tmap),
+            pl.BlockSpec((G, bq, hd), qmap),
+            pl.BlockSpec((1, bt, KV, hd), pmap),
+            pl.BlockSpec((1, bt, KV, hd), pmap),
+            pl.BlockSpec((bt, KV, hd), tmap),
+            pl.BlockSpec((bt, KV, hd), tmap),
         ],
-        out_specs=pl.BlockSpec((1, bq, hd), qmap),
+        out_specs=pl.BlockSpec((G, bq, hd), qmap),
         scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
+            pltpu.VMEM((G, bq, LANES), jnp.float32),
+            pltpu.VMEM((G, bq, LANES), jnp.float32),
+            pltpu.VMEM((G, bq, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(

@@ -1,9 +1,13 @@
 """Jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True``; on a
-TPU backend they compile to Mosaic. ``use_kernel=False`` dispatches to the
-pure-jnp oracle in :mod:`repro.kernels.ref` — the serving engine uses the
-oracle path on CPU for speed, while tests sweep the kernels against it.
+On a CPU backend the kernels execute with ``interpret=True``; on a TPU
+backend they compile to Mosaic. ``use_kernel=False`` dispatches to the
+pure-jnp oracle in :mod:`repro.kernels.ref`, which the tests sweep the
+kernels against. The serving engine calls none of these wrappers yet: its
+restore is the XLA scatter of ``core.restore.fused_restore_family_shared``
+and its decode and recovery attention are XLA gathers over the page pool
+(``models.transformer._decode_attention_paged``, ``core.pic``).
+``chip_smoke.py`` runs the kernels on the chip against the oracles.
 """
 from __future__ import annotations
 
@@ -212,10 +216,17 @@ def flash_decode_paged(q, pool_k, pool_v, page_idx, tail_k=None, tail_v=None,
     else:
         tail_k = _pad_axis(tail_k, 0, Tp)
         tail_v = _pad_axis(tail_v, 0, Tp)
+    # the G query heads of each KV head become the rows of one tile,
+    # padded to a whole number of sublane tiles
+    H, _, hd = q.shape
+    KV = pool_k.shape[2]
+    G = H // KV
+    Gp = -(-G // Q_ROWS) * Q_ROWS
     out = flash_decode_paged_kernel(
-        _pad_axis(q, 1, Q_ROWS), pool_k, pool_v, page_idx, tail_k, tail_v,
-        span_len=span_len, tail_len=T, window=window, interpret=_interpret())
-    return out[:, :1]
+        _pad_axis(q.reshape(KV, G, hd), 1, Gp), pool_k, pool_v, page_idx,
+        tail_k, tail_v, span_len=span_len, tail_len=T, window=window,
+        interpret=_interpret())
+    return out[:, :G].reshape(H, 1, hd)
 
 
 # --------------------------------------------------------------------------

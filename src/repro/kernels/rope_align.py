@@ -16,12 +16,14 @@ from jax.experimental import pallas as pl
 
 def _kernel(k_ref, delta_ref, o_ref, *, theta: float):
     k = k_ref[...]                       # [ts, KV, hd]
-    delta = delta_ref[...]               # [ts]
+    delta = delta_ref[...]               # [ts, 1]
     ts, KV, hd = k.shape
     half = hd // 2
-    exps = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1) / half
+    # Mosaic's iota is integer-only: count in int32, then cast
+    exps = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(
+        jnp.float32) / half
     freqs = jnp.exp(-exps * jnp.log(theta))
-    ang = delta.astype(jnp.float32)[:, None] * freqs        # [ts, half]
+    ang = delta.astype(jnp.float32) * freqs                 # [ts, half]
     cos = jnp.cos(ang)[:, None, :]
     sin = jnp.sin(ang)[:, None, :]
     kf = k.astype(jnp.float32)
@@ -42,14 +44,16 @@ def rope_align_kernel(
     S, KV, hd = k.shape
     tile_s = min(tile_s, S)
     assert S % tile_s == 0, "pad S to the token tile"
-    delta = (tgt_pos - src_pos).astype(jnp.int32)
+    # a [S, 1] column: its (tile_s, 1) block keeps the token axis on
+    # sublanes, where the [ts, half] angle table needs it
+    delta = (tgt_pos - src_pos).astype(jnp.int32).reshape(S, 1)
     grid = (S // tile_s,)
     return pl.pallas_call(
         functools.partial(_kernel, theta=theta),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_s, KV, hd), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tile_s,), lambda i: (i,)),
+            pl.BlockSpec((tile_s, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tile_s, KV, hd), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((S, KV, hd), k.dtype),

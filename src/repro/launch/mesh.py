@@ -16,26 +16,15 @@ HBM_BW = 819e9               # bytes/s per chip
 ICI_BW = 50e9                # bytes/s per link
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the installed jax has it.
-
-    ``jax.sharding.AxisType`` landed after 0.4.x (the explicit-sharding
-    rework); every axis defaults to Auto there anyway, so omitting the
-    argument is behaviour-identical on older versions.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
     """A small mesh over whatever devices exist (tests / CPU smoke)."""
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"), **_mesh_kwargs(2))
+    return jax.make_mesh((1, n), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -38,8 +38,14 @@ def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
 
+@partial(jax.jit, static_argnums=1)
 def init_params(rng: jax.Array, cfg: ModelConfig) -> dict:
-    """Initialize a parameter pytree with stacked layer leaves ([L, ...])."""
+    """Initialize a parameter pytree with stacked layer leaves ([L, ...]).
+
+    One jitted program: each leaf is drawn and cast inside it, so a weight
+    is created on the device in ``cfg.dtype`` and never exists there as
+    its float32 draw. Threefry draws the same values under jit as eagerly.
+    """
     dt = _dtype(cfg)
     L, D = cfg.n_layers, cfg.d_model
     hd, H, KV = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads

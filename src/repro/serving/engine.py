@@ -74,7 +74,7 @@ class DecodeState:
     behind the bit-exact oracle relationship.
     """
 
-    step: Callable                 # jitted (tok, cache) -> (tok, cache)
+    step: Callable                 # jitted (params, tok, cache) -> (tok, cache)
     tok: jax.Array                 # last greedy token, [N]
     cache: dict                    # dense or paged decode cache
     outs: list = field(default_factory=list)   # per-step tokens, [N] each
@@ -247,9 +247,8 @@ class ServingEngine:
             }
             key = ("decode_paged", N, total)
             if key not in self.rt.jit:
-                def f(tok, cache):
-                    logits, cache = decode_step_paged(
-                        self.params, cfg, tok, cache)
+                def f(params, tok, cache):
+                    logits, cache = decode_step_paged(params, cfg, tok, cache)
                     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             cache)
                 self.rt.jit[key] = jax.jit(f)
@@ -273,15 +272,15 @@ class ServingEngine:
                     cache[key_] = prefill_cache[key_]
             key = ("decode", N, total)
             if key not in self.rt.jit:
-                def f(tok, cache):
-                    logits, cache = decode_step(self.params, cfg, tok, cache)
+                def f(params, tok, cache):
+                    logits, cache = decode_step(params, cfg, tok, cache)
                     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             cache)
                 self.rt.jit[key] = jax.jit(f)
         step = self.rt.jit[key]
         tok = jnp.argmax(first_logits, axis=-1).astype(jnp.int32)
         if key not in self.rt.warm:
-            jax.block_until_ready(step(tok, cache))
+            jax.block_until_ready(step(self.params, tok, cache))
             self.rt.warm.add(key)
         return DecodeState(step=step, tok=tok, cache=cache, outs=[tok],
                            gaids=list(gaids), S=S, G=G, bt=bt,
@@ -295,7 +294,7 @@ class ServingEngine:
         if st.paged and (st.S + st.t) % st.bt == 0:
             for a in st.gaids:
                 self.manager.append_page(f"round:{a}")
-        st.tok, st.cache = st.step(st.tok, st.cache)
+        st.tok, st.cache = st.step(self.params, st.tok, st.cache)
         st.outs.append(st.tok)
         st.t += 1
 
@@ -477,6 +476,7 @@ class ServingEngine:
         # tokens and grows one page per block boundary via append_page,
         # reaching the same S+G total by round end.
         use_paged = self._paged_decode_ok(res.cache, S)
+        stats.merge_reuse("decode", {"paged": use_paged})
         self.manager.free_transient()
         for a in gaids:
             self.manager.free(f"round:{a}")

@@ -294,6 +294,7 @@ class ContinuousEngine:
         for part in item.data["parts"]:
             N, S = part["tokens"].shape
             part["use_paged"] = eng._paged_decode_ok(part["res"].cache, S)
+            stats.merge_reuse("decode", {"paged": part["use_paged"]})
             for a in part["aids"]:
                 eng.manager.alloc_tokens(
                     f"round:{a}",

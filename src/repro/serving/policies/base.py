@@ -208,11 +208,11 @@ class ReusePolicy(ABC):
         N, S = tokens.shape
         key = ("prefill", N, S)
         if key not in rt.jit:
-            def f(toks):
-                logits, cache = prefill(rt.params, rt.cfg, toks, max_len=S)
+            def f(params, toks):
+                logits, cache = prefill(params, rt.cfg, toks, max_len=S)
                 return logits[:, -1], cache
             rt.jit[key] = jax.jit(f)
-        (logits, cache), dt = rt.timed(key, rt.jit[key], tokens)
+        (logits, cache), dt = rt.timed(key, rt.jit[key], rt.params, tokens)
         return RecoveryResult(logits, cache, dt, {})
 
 

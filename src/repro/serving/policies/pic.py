@@ -76,7 +76,10 @@ class PICPolicy(ReusePolicy):
         layouts, aids = ctx.layouts, ctx.agent_ids
         L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.resolved_head_dim
         S = layouts[0].length
-        shared_k = jnp.zeros((L, S, KV, hd), jnp.float32)
+        # cached KV in the model's dtype: the recovery pass embeds tokens
+        # in shared_k's dtype, so a float32 buffer would lift a bf16 model
+        # (and every cache it hands to decode) to float32
+        shared_k = jnp.zeros((L, S, KV, hd), cfg.dtype)
         shared_v = jnp.zeros_like(shared_k)
         src = np.arange(S, dtype=np.int32)
         shared_mask = np.zeros(S, bool)
@@ -128,7 +131,7 @@ class PICPolicy(ReusePolicy):
         pks, pvs, srcs = [], [], []
         for e in entries:
             assert e.k.shape[1] == len(hspan), (e.k.shape, len(hspan))
-            full_k = jnp.zeros((L, S, KV, hd), jnp.float32)
+            full_k = jnp.zeros((L, S, KV, hd), cfg.dtype)
             full_v = jnp.zeros_like(full_k)
             full_k = full_k.at[:, hspan.start : hspan.end].set(e.k)
             full_v = full_v.at[:, hspan.start : hspan.end].set(e.v)
@@ -196,7 +199,8 @@ class PICPolicy(ReusePolicy):
             v = res.pic.recovered_v
             logits = res.pic.logits
             info = {"n_sel": n_sel, "plan": res.plan,
-                    "align_passes": rt.collector.align_passes - p0}
+                    "align_passes": rt.collector.align_passes - p0,
+                    "priv_mode": res.priv_mode}
         else:
             key = ("serial", S, n_sel)
             if key not in rt.warm:

@@ -72,7 +72,7 @@ class PrefixCachePolicy(ReusePolicy):
         vpre = jnp.stack([rt.sessions[a].dense_v[:, :p] for a in aids], axis=1)
         key = ("extend", N, S, p)
         if key not in rt.jit:
-            def f(toks, kp, vp):
+            def f(params, toks, kp, vp):
                 pad = S - p
                 cache = {
                     "k": jnp.pad(kp, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))),
@@ -83,10 +83,11 @@ class PrefixCachePolicy(ReusePolicy):
                         jnp.arange(S)[None] < p, (N, S)),
                     "length": jnp.full((N,), p, jnp.int32),
                 }
-                logits, cache = extend(rt.params, rt.cfg, toks[:, p:], cache)
+                logits, cache = extend(params, rt.cfg, toks[:, p:], cache)
                 return logits[:, -1], {"k": cache["k"], "v": cache["v"]}
             rt.jit[key] = jax.jit(f)
-        (logits, cache), dt = rt.timed(key, rt.jit[key], tokens, kpre, vpre)
+        (logits, cache), dt = rt.timed(key, rt.jit[key], rt.params, tokens,
+                                       kpre, vpre)
         return RecoveryResult(logits, cache, dt, {"prefix_len": p})
 
     def store(self, ctx: RoundContext, cache: dict, outputs: np.ndarray,

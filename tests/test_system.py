@@ -151,3 +151,35 @@ def test_memory_fallback_degrades_service():
     fits = simulate_round_latency(st, 4, qps=1, pool_budget_bytes=1000)
     over = simulate_round_latency(st, 4, qps=1, pool_budget_bytes=200)
     assert over > fits
+
+
+@pytest.mark.parametrize("mode,kw", [("prefix", {}),
+                                     ("tokendance", {"paged_decode": False})])
+def test_jitted_steps_take_weights_as_arguments(setup, monkeypatch, mode, kw):
+    """Every model step the engine jits receives the weights as an
+    argument. A closed-over weight is lowered into the program as a dense
+    constant — at full width on a chip, gigabytes of them per program."""
+    cfg, params = setup
+    real_jit = jax.jit
+    calls = {}
+
+    def spy_jit(fn, *a, **k):
+        jitted = real_jit(fn, *a, **k)
+        if not fn.__module__.startswith("repro."):
+            return jitted
+
+        def call(*args):
+            calls.setdefault(jitted, args)
+            return jitted(*args)
+        return call
+
+    monkeypatch.setattr(jax, "jit", spy_jit)
+    _run(cfg, params, mode, **kw)
+    monkeypatch.undo()
+    # prefix: prefill + extend + paged decode; tokendance: prefill +
+    # collective recovery + dense decode
+    assert len(calls) >= 3, calls
+    for jitted, args in calls.items():
+        assert any(a is params for a in args), jitted
+        text = jitted.lower(*args).as_text()
+        assert max(map(len, text.splitlines())) < 1 << 16, jitted
