@@ -55,7 +55,8 @@ def main() -> None:
                             topology=topology, gen_len=args.gen,
                             recompute_ratio=0.1)
         print(f"\n== policy={name} agents={args.agents} "
-              f"workload={args.workload} topology={args.topology}")
+              f"workload={args.workload} topology={args.topology} "
+              "(times include each new shape's first call)")
         for s in eng.serve(trace):
             line = (f"  round {s.round_idx}: S={s.prompt_len} "
                     f"recover={s.t_recover*1e3:6.0f}ms "

@@ -233,13 +233,20 @@ class KVCollector:
     the TPU tile-aligned variant that keeps Mirror diffs block-sparse),
     ``pooled_selection`` (one pooled selected set per group — a
     beyond-paper option, off by default), ``shard`` (layer-output
-    sharding hook for the multi-device path).
+    sharding hook for the multi-device path), ``programs`` (the
+    :class:`~repro.serving.trace.JitCache` its recovery programs are
+    built in — the serving engine shares its own, so they are named,
+    counted and traced with the engine's; a private one by default).
     """
 
     def __init__(self, params: dict, cfg: ModelConfig, *, check_layer: int = 1,
                  recompute_ratio: float = 0.15, block_select: int = 0,
-                 pooled_selection: bool = False, shard=None):
+                 pooled_selection: bool = False, shard=None, programs=None):
         from repro.models.layers import _noshard
+        if programs is None:
+            # imported here: repro.serving imports this module
+            from repro.serving.trace import JitCache
+            programs = JitCache()
         self.params = params
         self.cfg = cfg
         self.check_layer = min(check_layer, cfg.n_layers - 1)
@@ -247,8 +254,7 @@ class KVCollector:
         self.block_select = block_select
         self.pooled_selection = pooled_selection
         self.shard = shard or _noshard
-        # jit caches keyed by (S, n_sel, share)
-        self._jit_cache: dict = {}
+        self.programs = programs
         # counted work: one unit per RoPE-align + selection pass launched.
         # Wall-clock is CI-contention-flaky; tests assert on this instead.
         self.align_passes = 0
@@ -279,8 +285,7 @@ class KVCollector:
         psrc, pmask) and ``paged_meta = (start, span_len, has_tail)``
         are the static placement params.
         """
-        key = (S, n_sel, share, priv_mode, paged_meta)
-        if key not in self._jit_cache:
+        def build():
             def run(params, tokens, ck, cv, src, shared_mask, *args):
                 pk = pv = psrc = pmask = None
                 hist = None
@@ -308,8 +313,10 @@ class KVCollector:
                     check_layer=self.check_layer,
                     pooled_selection=share and self.pooled_selection,
                     block_select=self.block_select, shard=self.shard)
-            self._jit_cache[key] = jax.jit(run)
-        return self._jit_cache[key]
+            return run
+        return self.programs.get_jit(
+            "collective_recover" if share else "serial_recover",
+            (S, n_sel, priv_mode, paged_meta), build)
 
     @staticmethod
     def _priv_args(priv, paged_attention: bool = True) -> Tuple[str, tuple, tuple]:

@@ -43,6 +43,7 @@ from repro.serving.scheduler import (
     simulate_round_latency,
 )
 from repro.serving.state import RoundStats, Session
+from repro.serving.trace import Tracer
 
 __all__ = [
     # engine
@@ -51,6 +52,8 @@ __all__ = [
     "ServingEngine",
     "RoundStats",
     "Session",
+    # spans of the serving engine
+    "Tracer",
     # policies
     "POLICIES",
     "PICPolicy",

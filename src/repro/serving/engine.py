@@ -21,10 +21,17 @@ admission via :class:`~repro.serving.planner.RoundPlanner`.
 
 ``MultiAgentEngine(mode=...)`` remains as a deprecated string-keyed shim
 with bit-exact behavior.
+
+``tracer`` (a :class:`~repro.serving.trace.Tracer`) times the layers
+where the work happens: spans ``round``, ``prompts``, ``plan`` (child
+``restore``), ``recover``, ``decode`` (child ``decode.step``), ``store``
+(child ``store.family``) and ``jit:<program>`` around the first call of
+each new program. ``RoundStats.t_*`` are the round's sums of those spans,
+so they include a new shape's first call (trace, compile, dispatch).
+``None`` times without recording.
 """
 from __future__ import annotations
 
-import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple, Union
@@ -55,6 +62,7 @@ from repro.serving.policies import (
     get_policy,
 )
 from repro.serving.state import RoundStats, Session
+from repro.serving.trace import JitCache, Tracer
 
 MODES = ("recompute", "prefix", "pic", "tokendance")
 
@@ -68,8 +76,8 @@ class DecodeState:
     tight loop (:meth:`ServingEngine._decode_dense` /
     :meth:`ServingEngine._decode_paged`); the continuous engine
     (``serving/loop``) holds several of these open at once and advances
-    each on its scheduler tick. Both paths share the jit cache keyed by
-    (kind, N, S+G), so an interleaved decode compiles and computes
+    each on its scheduler tick. Both paths share the program cache keyed
+    by (kind, N, S+G), so an interleaved decode compiles and computes
     exactly what the synchronized loop does — this is the mechanism
     behind the bit-exact oracle relationship.
     """
@@ -84,7 +92,8 @@ class DecodeState:
     bt: int = 0                    # block_tokens (paged page tile)
     paged: bool = False
     t: int = 0                     # decode steps taken (of G-1)
-    t0: float = 0.0
+    round_idx: int = 0             # for the decode.step spans
+    gid: str = ""
 
     @property
     def done(self) -> bool:
@@ -114,6 +123,7 @@ class ServingEngine:
         paged_decode: bool = True,
         keep_recovered: bool = False,
         keep_logits: bool = False,
+        tracer: Optional[Tracer] = None,
     ):
         if isinstance(policy, str):
             policy = get_policy(policy)
@@ -131,7 +141,9 @@ class ServingEngine:
         self.topology = topology or AllGather()
         self.sessions: Dict[str, Session] = {}
         self.segment_index = SegmentIndex()
-        self.pool = PagedKVPool(cfg, pool_pages)
+        # pages at the model's dtype, so the ledger counts the bytes the
+        # KV really takes
+        self.pool = PagedKVPool(cfg, pool_pages, dtype=cfg.dtype)
         # tiered layer over the pool: family-aware eviction + host
         # offload + restore-ahead prefetch. host_offload=False disables
         # the host tier (capacity 0), reproducing the hard-wall
@@ -149,14 +161,18 @@ class ServingEngine:
         self.keep_logits = keep_logits
         self.last_recovered: Optional[tuple] = None
         self._recovered_parts: list = []
+        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
+        self.programs = JitCache(self.tracer)
         self.collector = KVCollector(
             params, cfg, check_layer=check_layer,
-            recompute_ratio=recompute_ratio, block_select=block_select)
+            recompute_ratio=recompute_ratio, block_select=block_select,
+            programs=self.programs)
         self.rt = PolicyRuntime(
             params=params, cfg=cfg, gen_len=gen_len, ratio=recompute_ratio,
             block_select=block_select, sep_id=self.sep_id,
             sessions=self.sessions, segment_index=self.segment_index,
-            pool=self.pool, manager=self.manager, collector=self.collector)
+            pool=self.pool, manager=self.manager, collector=self.collector,
+            tracer=self.tracer, programs=self.programs)
         policy.bind(self.rt)
         self.policy = policy
         self.mode = policy.name          # legacy-facing alias
@@ -207,12 +223,12 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     def _decode_begin(self, first_logits, prefill_cache: dict, N: int,
-                      S: int, gaids: List[str], use_paged: bool
-                      ) -> DecodeState:
-        """Build the decode cache, jit the step function (shared cache
-        keyed by (kind, N, S+G)), take the first greedy token from the
-        recovery logits, and warm the step — everything up to (but not
-        including) the first decode step. The returned
+                      S: int, gaids: List[str], use_paged: bool,
+                      gid: str, round_idx: int) -> DecodeState:
+        """Build the decode cache, get the step program (keyed by (kind,
+        N, S+G)) and take the first greedy token from the recovery
+        logits — everything up to (but not including) the first decode
+        step. The returned
         :class:`DecodeState` is then advanced by :meth:`_decode_advance`
         one model step at a time and closed by :meth:`_decode_finish`."""
         cfg, G = self.cfg, self.gen_len
@@ -245,13 +261,14 @@ class ServingEngine:
                                     ((0, 0), (0, G))),
                 "length": jnp.full((N,), S, jnp.int32),
             }
-            key = ("decode_paged", N, total)
-            if key not in self.rt.jit:
+            def build():
                 def f(params, tok, cache):
                     logits, cache = decode_step_paged(params, cfg, tok, cache)
                     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             cache)
-                self.rt.jit[key] = jax.jit(f)
+                return f
+            step = self.programs.get_jit("decode_step_paged", (N, total),
+                                         build)
         else:
             cache = {"length": jnp.full((N,), S, jnp.int32)}
             if "k" in prefill_cache:
@@ -270,53 +287,58 @@ class ServingEngine:
             for key_ in ("ssm", "conv"):
                 if key_ in prefill_cache:
                     cache[key_] = prefill_cache[key_]
-            key = ("decode", N, total)
-            if key not in self.rt.jit:
+            def build():
                 def f(params, tok, cache):
                     logits, cache = decode_step(params, cfg, tok, cache)
                     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             cache)
-                self.rt.jit[key] = jax.jit(f)
-        step = self.rt.jit[key]
+                return f
+            step = self.programs.get_jit("decode_step_dense", (N, total),
+                                         build)
         tok = jnp.argmax(first_logits, axis=-1).astype(jnp.int32)
-        if key not in self.rt.warm:
-            jax.block_until_ready(step(self.params, tok, cache))
-            self.rt.warm.add(key)
         return DecodeState(step=step, tok=tok, cache=cache, outs=[tok],
                            gaids=list(gaids), S=S, G=G, bt=bt,
-                           paged=use_paged, t0=time.perf_counter())
+                           paged=use_paged, round_idx=round_idx, gid=gid)
 
     def _decode_advance(self, st: DecodeState) -> None:
         """One greedy decode step. On the paged path, the write at
         position S+t opens a fresh gen page each time generation crosses
         a block boundary: claim it in the ledger before the step fills
         its first slot (the previous page is sealed from here on)."""
-        if st.paged and (st.S + st.t) % st.bt == 0:
-            for a in st.gaids:
-                self.manager.append_page(f"round:{a}")
-        st.tok, st.cache = st.step(self.params, st.tok, st.cache)
+        with self.tracer.span("decode.step", round=st.round_idx, gid=st.gid,
+                              step=st.t):
+            if st.paged and (st.S + st.t) % st.bt == 0:
+                for a in st.gaids:
+                    self.manager.append_page(f"round:{a}")
+            st.tok, st.cache = st.step(self.params, st.tok, st.cache)
         st.outs.append(st.tok)
         st.t += 1
 
     def _decode_finish(self, st: DecodeState):
-        """Materialize the decode: outputs [N, G] on host, the final
-        cache, and the wall-clock spent since :meth:`_decode_begin`
-        (reported, never gated — CI gates counted work only)."""
-        jax.block_until_ready(st.tok)
-        dt = time.perf_counter() - st.t0
-        return (np.stack([np.asarray(t) for t in st.outs], axis=1),
-                st.cache, dt)
+        """Materialize the decode: outputs [N, G] on host (which waits for
+        the last step) and the final cache."""
+        return np.stack([np.asarray(t) for t in st.outs], axis=1), st.cache
 
-    def _decode_dense(self, first_logits, prefill_cache: dict, N: int, S: int):
+    def _decode(self, first_logits, prefill_cache: dict, N: int, S: int,
+                gaids: List[str], gid: str, use_paged: bool):
+        """begin → advance×(G-1) → finish inside one ``decode`` span;
+        returns (outputs, cache, seconds)."""
+        with self.tracer.span("decode", gid=gid, paged=use_paged) as sp:
+            st = self._decode_begin(first_logits, prefill_cache, N, S,
+                                    gaids, use_paged, gid, self.round_idx)
+            while not st.done:
+                self._decode_advance(st)
+            outputs, cache = self._decode_finish(st)
+        return outputs, cache, sp.dt
+
+    def _decode_dense(self, first_logits, prefill_cache: dict, N: int, S: int,
+                      gid: str):
         """Greedy decode gen_len tokens for the group over a dense padded
         [L, N, S+G] cache (attention KV, SSM state, or both) — the
         fallback for SSM/hybrid state and the bit-exact oracle the paged
         loop is pinned against."""
-        st = self._decode_begin(first_logits, prefill_cache, N, S,
-                                gaids=[], use_paged=False)
-        while not st.done:
-            self._decode_advance(st)
-        return self._decode_finish(st)
+        return self._decode(first_logits, prefill_cache, N, S, [], gid,
+                            use_paged=False)
 
     # ------------------------------------------------------------------
     def _paged_decode_ok(self, prefill_cache: dict, S: int) -> bool:
@@ -332,7 +354,7 @@ class ServingEngine:
                 and S % bt == 0 and self.gen_len % bt == 0)
 
     def _decode_paged(self, first_logits, prefill_cache: dict, N: int,
-                      S: int, gaids: List[str]):
+                      S: int, gaids: List[str], gid: str):
         """Greedy decode whose attention KV lives in round pool pages —
         the recovered prefill KV becomes each agent's sealed pages and
         every generated token is scatter-written into the open gen page,
@@ -342,15 +364,17 @@ class ServingEngine:
         dense loop (pinned in tests), and ledger page claims land on the
         same end-of-round totals as the dense loop's up-front S+G
         allocation."""
-        st = self._decode_begin(first_logits, prefill_cache, N, S,
-                                gaids=gaids, use_paged=True)
-        while not st.done:
-            self._decode_advance(st)
-        return self._decode_finish(st)
+        return self._decode(first_logits, prefill_cache, N, S, gaids, gid,
+                            use_paged=True)
 
     # ------------------------------------------------------------------
     def run_round(self, rnd: Round, plan: Optional[RoundPlan] = None,
                   next_plan: Optional[RoundPlan] = None) -> RoundStats:
+        with self.tracer.span("round", round=self.round_idx):
+            return self._run_round(rnd, plan, next_plan)
+
+    def _run_round(self, rnd: Round, plan: Optional[RoundPlan],
+                   next_plan: Optional[RoundPlan]) -> RoundStats:
         # generate mode: use previous outputs as this round's shared blocks.
         # Agents that have not produced yet (deferred by admission since
         # round 0) contribute their trace replay block instead.
@@ -393,7 +417,8 @@ class ServingEngine:
         if self.keep_recovered:
             self._recovered_parts = []
         for gi, gaids in enumerate(groups):
-            parts = self._build_prompts(rnd, gaids, sources)
+            with self.tracer.span("prompts", gid=f"g{gi}"):
+                parts = self._build_prompts(rnd, gaids, sources)
             for pj, (paids, tokens_np, layouts) in enumerate(parts):
                 gid = f"g{gi}" if len(parts) == 1 else f"g{gi}.{pj}"
                 for a, row, lg in self._run_group(
@@ -429,6 +454,8 @@ class ServingEngine:
         pool_delta["persistent_host_bytes"] = host_bytes
         pool_delta["restore_cache_bytes"] = cache_bytes
         stats.merge_reuse("pool", pool_delta)
+        stats.merge_reuse(
+            "jit", {"new_programs": self.programs.take_new_programs()})
         self.round_idx += 1
         return stats
 
@@ -455,10 +482,15 @@ class ServingEngine:
                            tokens=tokens_np)
 
         # ---- phase A: plan (host) + recover (jitted) --------------------
-        rplan = self.policy.plan(ctx)
-        res = self.policy.recover(rplan, tokens)
-        stats.t_recover += res.t_recover
-        stats.t_restore += rplan.t_restore
+        tr = self.tracer
+        restored = tr.total("restore")
+        with tr.span("plan", gid=gid):
+            rplan = self.policy.plan(ctx)
+        stats.t_restore += tr.total("restore") - restored
+        with tr.span("recover", gid=gid, kind=rplan.kind,
+                     n_sel=rplan.n_sel) as sp:
+            res = self.policy.recover(rplan, tokens)
+        stats.t_recover += sp.dt
         for k_, v_ in res.info.items():
             if k_ != "plan":
                 stats.merge_reuse(k_, v_)
@@ -495,19 +527,19 @@ class ServingEngine:
         # ---- phase C: decode --------------------------------------------
         if use_paged:
             outputs, cache, dt_dec = self._decode_paged(
-                res.logits, res.cache, N, S, gaids)
+                res.logits, res.cache, N, S, gaids, gid)
         else:
             outputs, cache, dt_dec = self._decode_dense(
-                res.logits, res.cache, N, S)
+                res.logits, res.cache, N, S, gid)
         stats.t_decode += dt_dec
 
         # ---- phase D: bookkeeping / storage -----------------------------
-        t0 = time.perf_counter()
-        for i, a in enumerate(gaids):
-            self.sessions[a].state.extend_history(outputs[i])
-            self.last_outputs[a] = outputs[i]
-        self.policy.store(ctx, cache, outputs, res, stats)
-        stats.t_store += time.perf_counter() - t0
+        with tr.span("store", gid=gid) as sp:
+            for i, a in enumerate(gaids):
+                self.sessions[a].state.extend_history(outputs[i])
+                self.last_outputs[a] = outputs[i]
+            self.policy.store(ctx, cache, outputs, res, stats)
+        stats.t_store += sp.dt
         logits_np = (np.asarray(res.logits) if self.keep_logits
                      else [None] * N)
         return [(a, outputs[i], logits_np[i]) for i, a in enumerate(gaids)]

@@ -14,7 +14,7 @@ prefetch plug in per committee-round.
 Bit-exactness contract (the oracle relationship, pinned in tests): the
 continuous engine performs exactly the synchronized engine's
 computations — same prompt construction, same policy calls with the
-same ``RoundContext``, same jit cache keyed by (kind, N, S+G), same
+same ``RoundContext``, same program cache keyed by (kind, N, S+G), same
 decode step sequence per committee — merely interleaved across
 committees. Committees are computationally independent (disjoint
 sessions, disjoint Master families; a committee's prompts read only its
@@ -22,6 +22,12 @@ own members' output blocks), and the pool's spill/reload seam is
 bit-exact by construction, so interleaving cannot change any output.
 On a single-committee trace the schedules coincide call for call and
 outputs AND logits match the synchronized ``serve()`` bit for bit.
+
+``RoundStats.t_*`` come from the wrapped engine's tracer, span by span:
+``plan`` (its ``restore`` children fill ``t_restore``), ``recover``,
+``decode`` around the begin and the finish of a committee's decode plus
+its ``decode.step`` spans (``t_decode`` is that committee's own decode
+work, not the wall time its decode stayed open), and ``store``.
 
 What "one global decode batch" means here: DECODE-phase committees step
 on the same tick, each through its own jitted step function (the same
@@ -190,12 +196,15 @@ class ContinuousEngine:
         if item.phase != Phase.DECODE:
             return                      # restore/prefill drain is accounting
         eng = self.engine
+        steps0 = eng.tracer.total("decode.step")
         with eng.manager.scoped(f"g{item.committee}"):
             for _ in range(k):
                 for part in item.data["parts"]:
                     st: DecodeState = part["decode"]
                     eng._decode_advance(st)
                     self._stream_tokens(part, st, item.round_idx, tick)
+        item.data["stats"].t_decode += \
+            eng.tracer.total("decode.step") - steps0
 
     def phase_end(self, item: WorkItem, tick: int) -> None:
         if item.phase == Phase.PREFILL:
@@ -222,7 +231,8 @@ class ContinuousEngine:
         rnd = self._committee_round(r)
         parts = []
         if admitted:
-            built = eng._build_prompts(rnd, admitted, self._sources)
+            with eng.tracer.span("prompts", round=r, gid=f"g{c}"):
+                built = eng._build_prompts(rnd, admitted, self._sources)
             for pj, (paids, tokens_np, layouts) in enumerate(built):
                 gid = f"g{c}" if len(built) == 1 else f"g{c}.{pj}"
                 parts.append({"gid": gid, "aids": paids,
@@ -251,9 +261,11 @@ class ContinuousEngine:
                                agent_ids=list(part["aids"]),
                                layouts=part["layouts"],
                                tokens=part["tokens"])
-            rplan = eng.policy.plan(ctx)
+            restored = eng.tracer.total("restore")
+            with eng.tracer.span("plan", round=r, gid=part["gid"]):
+                rplan = eng.policy.plan(ctx)
             part["ctx"], part["rplan"] = ctx, rplan
-            stats.t_restore += rplan.t_restore
+            stats.t_restore += eng.tracer.total("restore") - restored
             units += self._restore_units(rplan.restore_info)
         return PhaseCost(units)
 
@@ -276,9 +288,11 @@ class ContinuousEngine:
         for part in item.data["parts"]:
             rplan = part["rplan"]
             tokens = jnp.asarray(part["tokens"])
-            res = eng.policy.recover(rplan, tokens)
+            with eng.tracer.span("recover", round=r, gid=part["gid"],
+                                 kind=rplan.kind, n_sel=rplan.n_sel) as sp:
+                res = eng.policy.recover(rplan, tokens)
             part["res"] = res
-            stats.t_recover += res.t_recover
+            stats.t_recover += sp.dt
             for k_, v_ in res.info.items():
                 if k_ != "plan":
                     stats.merge_reuse(k_, v_)
@@ -308,9 +322,12 @@ class ContinuousEngine:
         for part in item.data["parts"]:
             N, S = part["tokens"].shape
             res = part["res"]
-            part["decode"] = eng._decode_begin(
-                res.logits, res.cache, N, S, part["aids"],
-                part["use_paged"])
+            with eng.tracer.span("decode", round=r, gid=part["gid"],
+                                 paged=part["use_paged"]) as sp:
+                part["decode"] = eng._decode_begin(
+                    res.logits, res.cache, N, S, part["aids"],
+                    part["use_paged"], part["gid"], r)
+            item.data["stats"].t_decode += sp.dt
             n_agents += N
         # restore-ahead prefetch for this committee's round r+1, issued
         # per-phase: it overlaps THIS committee's decode ticks (and any
@@ -328,14 +345,18 @@ class ContinuousEngine:
         out_rows: Dict[str, np.ndarray] = {}
         logit_rows: Dict[str, np.ndarray] = {}
         for part in item.data["parts"]:
-            outputs, cache, dt_dec = eng._decode_finish(part["decode"])
-            stats.t_decode += dt_dec
-            for i, a in enumerate(part["aids"]):
-                eng.sessions[a].state.extend_history(outputs[i])
-                eng.last_outputs[a] = outputs[i]
-                out_rows[a] = outputs[i]
-            eng.policy.store(part["ctx"], cache, outputs, part["res"],
-                             stats)
+            with eng.tracer.span("decode", round=r, gid=part["gid"],
+                                 paged=part["use_paged"]) as sp:
+                outputs, cache = eng._decode_finish(part["decode"])
+            stats.t_decode += sp.dt
+            with eng.tracer.span("store", round=r, gid=part["gid"]) as sp:
+                for i, a in enumerate(part["aids"]):
+                    eng.sessions[a].state.extend_history(outputs[i])
+                    eng.last_outputs[a] = outputs[i]
+                    out_rows[a] = outputs[i]
+                eng.policy.store(part["ctx"], cache, outputs, part["res"],
+                                 stats)
+            stats.t_store += sp.dt
             logits_np = (np.asarray(part["res"].logits)
                          if eng.keep_logits else None)
             for i, a in enumerate(part["aids"]):
@@ -360,6 +381,9 @@ class ContinuousEngine:
         pool_delta["persistent_host_bytes"] = host
         pool_delta["restore_cache_bytes"] = cache_b
         stats.merge_reuse("pool", pool_delta)
+        # programs first called since the last committee-round ended
+        stats.merge_reuse(
+            "jit", {"new_programs": eng.programs.take_new_programs()})
         res_out.stats[c].append(stats)
         for part in item.data["parts"]:
             for a in part["aids"]:

@@ -14,7 +14,10 @@ round, records the decision on ``RoundStats.admission``, and feeds each
 served round's stats back through :meth:`RoundPlanner.observe` — with
 ``refit_every`` set, the capacity model is re-fit from measurement
 (:func:`~repro.serving.scheduler.service_times_from_stats`) instead of
-staying a static a-priori guess.
+staying a static a-priori guess. The measured ``RoundStats.t_*`` are
+sums of the engine's spans and include each new shape's first call
+(trace, compile, dispatch), so a refit over rounds that met new shapes
+reads compilation as service time.
 """
 from __future__ import annotations
 

@@ -15,15 +15,14 @@ engine drives every round, per gather group:
   :class:`~repro.serving.kvpool.PagedKVPool` ledger.
 
 Policies share a :class:`PolicyRuntime` (model substrate, sessions,
-segment index, pool, collector, jit caches) owned by the engine and
-handed over at :meth:`ReusePolicy.bind` time. A string-keyed registry
-(:func:`register_policy` / :func:`get_policy`) maps legacy mode strings
-onto policy classes so ``MultiAgentEngine(mode=...)`` keeps working as a
-deprecated shim.
+segment index, pool, collector, tracer, jitted programs) owned by the
+engine and handed over at :meth:`ReusePolicy.bind` time. A string-keyed
+registry (:func:`register_policy` / :func:`get_policy`) maps legacy mode
+strings onto policy classes so ``MultiAgentEngine(mode=...)`` keeps
+working as a deprecated shim.
 """
 from __future__ import annotations
 
-import time
 from abc import ABC, abstractmethod
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
@@ -39,6 +38,7 @@ from repro.models import prefill
 from repro.serving.kvpool import PagedKVPool
 from repro.serving.pool.manager import PoolManager, Spillable
 from repro.serving.state import Session
+from repro.serving.trace import JitCache, Tracer
 
 
 def entry_spillable(entry) -> Spillable:
@@ -57,9 +57,10 @@ def entry_spillable(entry) -> Spillable:
 class PolicyRuntime:
     """Shared serving substrate a policy executes against.
 
-    One runtime per engine; ``jit`` / ``warm`` are shared across the
-    policy and the engine's decode loop so shape-keyed compilations are
-    paid once regardless of which side triggers them.
+    One runtime per engine. ``programs`` is shared by the policy, the
+    collector and the engine's decode loop, so each shape-keyed program
+    compiles once whichever side calls it first; ``tracer`` times the
+    policy's own spans (``restore``, ``store.family``).
     """
 
     params: dict
@@ -72,12 +73,12 @@ class PolicyRuntime:
     segment_index: SegmentIndex
     pool: PagedKVPool
     collector: KVCollector
+    tracer: Tracer
+    programs: JitCache
     #: tiered pool manager (eviction/offload/prefetch) — policies route
     #: persistent allocations through it and call ``ensure_resident``
     #: before reading spillable state; None only in bare-runtime tests
     manager: Optional[PoolManager] = None
-    jit: dict = field(default_factory=dict)
-    warm: set = field(default_factory=set)
 
     # ---- pool routing: through the manager when the engine has one ----
     def pool_alloc(self, owner: str, n_pages: int, *, persistent: bool,
@@ -108,21 +109,6 @@ class PolicyRuntime:
         before reading any spillable state."""
         if self.manager is not None:
             self.manager.ensure_resident(owner)
-
-    def get_jit(self, key, builder):
-        if key not in self.jit:
-            self.jit[key] = jax.jit(builder())
-        return self.jit[key]
-
-    def timed(self, key, fn, *args):
-        """Warm up new shapes (compile excluded from timings), then time."""
-        if key not in self.warm:
-            jax.block_until_ready(fn(*args))
-            self.warm.add(key)
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        return out, time.perf_counter() - t0
 
 
 @dataclass
@@ -159,17 +145,16 @@ class RecoveryPlan:
     prefix_len: int = 0
     n_sel: int = 0
     assembled: Optional[tuple] = None   # (sk, sv, src, smask, priv, pmask, is_cached)
-    t_restore: float = 0.0              # mirror restore spent during plan
     restore_info: Optional[dict] = None # restore ledger for RoundStats.reuse
 
 
 @dataclass
 class RecoveryResult:
-    """Jitted-execution result: recovery logits + prefill-state cache."""
+    """Jitted-execution result: recovery logits + prefill-state cache,
+    both ready on the device when ``recover`` returns."""
 
     logits: jax.Array            # [N, V] last-token logits
     cache: dict                  # prefill cache ("k"/"v" and/or ssm state)
-    t_recover: float
     info: dict = field(default_factory=dict)
 
 
@@ -206,14 +191,15 @@ class ReusePolicy(ABC):
         """Full batched prefill — the universal fallback path."""
         rt = self.rt
         N, S = tokens.shape
-        key = ("prefill", N, S)
-        if key not in rt.jit:
+
+        def build():
             def f(params, toks):
                 logits, cache = prefill(params, rt.cfg, toks, max_len=S)
                 return logits[:, -1], cache
-            rt.jit[key] = jax.jit(f)
-        (logits, cache), dt = rt.timed(key, rt.jit[key], rt.params, tokens)
-        return RecoveryResult(logits, cache, dt, {})
+            return f
+        run = rt.programs.get_jit("prefill", (N, S), build)
+        logits, cache = jax.block_until_ready(run(rt.params, tokens))
+        return RecoveryResult(logits, cache, {})
 
 
 # --------------------------------------------------------------------------

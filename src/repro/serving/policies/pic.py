@@ -9,7 +9,6 @@ entries, dense-vs-paged ``priv`` construction — is what
 """
 from __future__ import annotations
 
-import time
 from typing import List
 
 import jax
@@ -51,23 +50,22 @@ class PICPolicy(ReusePolicy):
     def plan(self, ctx: RoundContext) -> RecoveryPlan:
         if ctx.round_idx == 0:
             return RecoveryPlan(kind="recompute", ctx=ctx)
-        t_restore, restore_info = self._restore_histories(ctx)
+        restore_info = self._restore_histories(ctx)
         assembled = self._assemble_cached(ctx)
         (sk, sv, src, smask, priv, pmask, is_cached) = assembled
         if not bool(np.asarray(smask).any() or np.asarray(pmask).any()):
             return RecoveryPlan(kind="recompute", ctx=ctx,
-                                t_restore=t_restore,
                                 restore_info=restore_info)
         fresh = ~np.asarray(is_cached)
         n_sel = n_sel_for_blocks(fresh, self.rt.block_select, self.rt.ratio)
         return RecoveryPlan(kind="reuse", ctx=ctx, n_sel=n_sel,
-                            assembled=assembled, t_restore=t_restore,
-                            restore_info=restore_info)
+                            assembled=assembled, restore_info=restore_info)
 
     def _restore_histories(self, ctx: RoundContext):
         """Hook for policies whose history caches live compressed between
-        rounds (TokenDance). The serial baseline keeps dense entries."""
-        return 0.0, None
+        rounds (TokenDance); returns the restore ledger, or None. The
+        serial baseline keeps dense entries."""
+        return None
 
     def _assemble_cached(self, ctx: RoundContext):
         """Build the shared cached arrays + per-agent history caches."""
@@ -181,20 +179,14 @@ class PICPolicy(ReusePolicy):
             # the serial baseline consumes dense priv tuples only
             priv = priv.materialize(S)
 
+        # one pass, and back once the recovered KV and the first-token
+        # logits are on the device: that is the time to first token
+        p0 = rt.collector.align_passes
         if self.collective:
-            key = ("coll", N, S, n_sel, self.paged_attention)
-            if key not in rt.warm:
-                rt.collector.collective_reuse(
-                    aids, tokens, sk, sv, src, smask, n_sel, priv,
-                    paged_attention=self.paged_attention)
-                rt.warm.add(key)
-            p0 = rt.collector.align_passes
-            t0 = time.perf_counter()
             res = rt.collector.collective_reuse(
                 aids, tokens, sk, sv, src, smask, n_sel, priv,
                 paged_attention=self.paged_attention)
-            jax.block_until_ready(res.pic.recovered_k)
-            dt = time.perf_counter() - t0
+            jax.block_until_ready((res.pic.recovered_k, res.pic.logits))
             k = res.pic.recovered_k                        # [L, N, S, KV, hd]
             v = res.pic.recovered_v
             logits = res.pic.logits
@@ -202,25 +194,16 @@ class PICPolicy(ReusePolicy):
                     "align_passes": rt.collector.align_passes - p0,
                     "priv_mode": res.priv_mode}
         else:
-            key = ("serial", S, n_sel)
-            if key not in rt.warm:
-                rt.collector.serial_reuse(
-                    aids[:1], tokens[:1], sk, sv, src, smask, n_sel,
-                    None if priv is None else tuple(
-                        x[:1] if i < 3 else x for i, x in enumerate(priv)))
-                rt.warm.add(key)
-            p0 = rt.collector.align_passes
-            t0 = time.perf_counter()
             results = rt.collector.serial_reuse(
                 aids, tokens, sk, sv, src, smask, n_sel, priv)
-            jax.block_until_ready([r.recovered_k for r in results])
-            dt = time.perf_counter() - t0
+            jax.block_until_ready([(r.recovered_k, r.logits)
+                                   for r in results])
             k = jnp.concatenate([r.recovered_k for r in results], axis=1)
             v = jnp.concatenate([r.recovered_v for r in results], axis=1)
             logits = jnp.concatenate([r.logits for r in results], axis=0)
             info = {"n_sel": n_sel,
                     "align_passes": rt.collector.align_passes - p0}
-        return RecoveryResult(logits, {"k": k, "v": v}, dt, info)
+        return RecoveryResult(logits, {"k": k, "v": v}, info)
 
     # ------------------------------------------------------------- store
     def _store_output_segments(self, ctx: RoundContext, kv,

@@ -70,8 +70,8 @@ class PrefixCachePolicy(ReusePolicy):
         N, S = tokens.shape
         kpre = jnp.stack([rt.sessions[a].dense_k[:, :p] for a in aids], axis=1)
         vpre = jnp.stack([rt.sessions[a].dense_v[:, :p] for a in aids], axis=1)
-        key = ("extend", N, S, p)
-        if key not in rt.jit:
+
+        def build():
             def f(params, toks, kp, vp):
                 pad = S - p
                 cache = {
@@ -85,10 +85,11 @@ class PrefixCachePolicy(ReusePolicy):
                 }
                 logits, cache = extend(params, rt.cfg, toks[:, p:], cache)
                 return logits[:, -1], {"k": cache["k"], "v": cache["v"]}
-            rt.jit[key] = jax.jit(f)
-        (logits, cache), dt = rt.timed(key, rt.jit[key], rt.params, tokens,
-                                       kpre, vpre)
-        return RecoveryResult(logits, cache, dt, {"prefix_len": p})
+            return f
+        run = rt.programs.get_jit("prefix_extend", (N, S, p), build)
+        logits, cache = jax.block_until_ready(
+            run(rt.params, tokens, kpre, vpre))
+        return RecoveryResult(logits, cache, {"prefix_len": p})
 
     def store(self, ctx: RoundContext, cache: dict, outputs: np.ndarray,
               result: RecoveryResult, stats) -> None:

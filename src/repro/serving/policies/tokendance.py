@@ -8,7 +8,6 @@ builds on top of PIC: per-family Diff-Aware Storage after the round
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional
 
 import jax.numpy as jnp
@@ -141,42 +140,43 @@ class TokenDancePolicy(PICPolicy):
             if fam is not None and fam in self.masters:
                 families.setdefault(fam, []).append(a)
         if not families:
-            return 0.0, None
-        t0 = time.perf_counter()
-        infos = []
-        for fi, (fam, members) in enumerate(families.items()):
-            master = self.masters[fam]
-            # the restore reads the family's compressed state and each
-            # member's output segment — pull any of it back from the
-            # host tier first (a prefetch issued last round makes these
-            # hits instead of synchronous reloads)
-            fam_owner = self._fam_owner(fam)
-            rt.ensure_resident(f"td:master:{fam_owner}")
-            rt.ensure_resident(f"td:mirrors:{fam_owner}")
-            for a in members:
-                rt.ensure_resident(f"out:{a}")
-            mirrors = [a for a in members if not rt.sessions[a].is_master]
-            # equal-length prompts give every family member the same span
-            span_len = rt.sessions[members[0]].hist_pending[0]
-            assert all(rt.sessions[a].hist_pending[0] == span_len
-                       for a in members)
-            gid = ctx.gid if len(families) == 1 else f"{ctx.gid}.f{fi}"
-            if self.paged_history:
-                info = None
-                if self.incremental:
-                    info = self._restore_incremental(
-                        ctx, fam, master, members, mirrors, span_len)
-                if info is None:
-                    infos.append(self._restore_paged(
-                        ctx, gid, master, members, mirrors, span_len,
-                        fam=fam))
+            return None
+        with rt.tracer.span("restore", families=len(families)) as sp:
+            infos = []
+            for fi, (fam, members) in enumerate(families.items()):
+                master = self.masters[fam]
+                # the restore reads the family's compressed state and each
+                # member's output segment — pull any of it back from the
+                # host tier first (a prefetch issued last round makes these
+                # hits instead of synchronous reloads)
+                fam_owner = self._fam_owner(fam)
+                rt.ensure_resident(f"td:master:{fam_owner}")
+                rt.ensure_resident(f"td:mirrors:{fam_owner}")
+                for a in members:
+                    rt.ensure_resident(f"out:{a}")
+                mirrors = [a for a in members if not rt.sessions[a].is_master]
+                # equal-length prompts give every family member the same span
+                span_len = rt.sessions[members[0]].hist_pending[0]
+                assert all(rt.sessions[a].hist_pending[0] == span_len
+                           for a in members)
+                gid = ctx.gid if len(families) == 1 else f"{ctx.gid}.f{fi}"
+                if self.paged_history:
+                    info = None
+                    if self.incremental:
+                        info = self._restore_incremental(
+                            ctx, fam, master, members, mirrors, span_len)
+                    if info is None:
+                        infos.append(self._restore_paged(
+                            ctx, gid, master, members, mirrors, span_len,
+                            fam=fam))
+                    else:
+                        infos.append(info)
                 else:
-                    infos.append(info)
-            else:
-                infos.append(self._restore_dense(
-                    ctx, master, members, mirrors, span_len))
-        info = infos[0] if len(infos) == 1 else infos
-        return time.perf_counter() - t0, info
+                    infos.append(self._restore_dense(
+                        ctx, master, members, mirrors, span_len))
+            sp.attrs["incremental"] = all(i.get("incremental", False)
+                                          for i in infos)
+        return infos[0] if len(infos) == 1 else infos
 
     def _restore_paged(self, ctx: RoundContext, gid: str,
                        master: MasterCache,
@@ -562,11 +562,12 @@ class TokenDancePolicy(PICPolicy):
         pk_all, pv_all = kv.slice(0, S)         # [L, N, S, KV, hd]
         ks = jnp.swapaxes(pk_all, 0, 1)         # [N, L, S, KV, hd]
         vs = jnp.swapaxes(pv_all, 0, 1)
-        master, handles = build_round_family(
-            aids, ks, vs, np.arange(S), master_idx,
-            block_tokens=rt.block_select or 32)
+        with rt.tracer.span("store.family", agents=len(aids)):
+            master, handles = build_round_family(
+                aids, ks, vs, np.arange(S), master_idx,
+                block_tokens=rt.block_select or 32)
+            cstats = compression_stats(master, handles)
         self.masters[ctx.group_key] = master
-        cstats = compression_stats(master, handles)
         stats.merge_reuse("compression", cstats)
         hi = 0
         for i, a in enumerate(aids):

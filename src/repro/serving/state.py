@@ -20,14 +20,17 @@ class RoundStats:
     mode: str                    # the serving policy's registry name
     n_agents: int
     prompt_len: int
-    t_recover: float = 0.0       # prefill / PIC recovery (s)
-    t_restore: float = 0.0       # mirror restore on the critical path (s)
-    t_decode: float = 0.0
-    t_store: float = 0.0         # diff build / segment extraction (s)
+    # seconds, the round's sums of the engine tracer's spans; a new
+    # shape's first call (trace, compile, dispatch) is inside them
+    t_recover: float = 0.0       # ``recover``: prefill / PIC recovery
+    t_restore: float = 0.0       # ``restore``: mirror restore in plan()
+    t_decode: float = 0.0        # ``decode``
+    t_store: float = 0.0         # ``store``: diff build / segment extraction
     persistent_bytes: int = 0    # cache state surviving the round
     transient_peak_bytes: int = 0
     outputs: Optional[np.ndarray] = None      # [N, G] generated tokens
     first_logits: Optional[np.ndarray] = None  # [N, V] recovery logits
+    #: reuse ledgers by name; "jit" = {"new_programs": {name: n}}
     reuse: dict = field(default_factory=dict)
     admission: Optional[dict] = None          # RoundPlanner decision
 

@@ -338,7 +338,7 @@ def test_engine_hands_collector_paged_private(engines):
     _, stats_p, _, _, seen = engines
     reuse_calls = [s for s in seen]
     assert "PagedPrivate" in reuse_calls, reuse_calls
-    # warm-up + timed call per reuse round, all paged
+    # one call per reuse round, all paged
     assert all(t == "PagedPrivate" for t in reuse_calls), reuse_calls
     for s in stats_p[1:]:
         assert s.reuse["restore"]["paged"] is True

@@ -12,6 +12,8 @@ SERVING_API = {
     "ServingEngine",
     "RoundStats",
     "Session",
+    # spans of the serving engine
+    "Tracer",
     # policy objects
     "POLICIES",
     "PICPolicy",
