@@ -84,8 +84,11 @@ class PagedPrivate:
                      cached value (identity outside the private span).
       mask:          bool [S] — True on the private-history span
                      ``[start, start + span_len + T)``.
-      start/span_len: static ints — placement of the paged span in the
-                     prompt. They key the collector's jit cache.
+      start/span_len: ints — placement of the paged span in the
+                     prompt. ``start`` keys the recovery program; the
+                     fast path takes ``span_len`` as an operand, so
+                     ``page_idx`` may carry padded columns past the span
+                     (any page; they are never read into the result).
     """
 
     pool_k: jax.Array
@@ -261,19 +264,25 @@ class KVCollector:
 
     # ------------------------------------------------------------------
     def _runner(self, S: int, n_sel: int, share: bool, priv_mode: str,
-                paged_meta: tuple = ()):
+                paged_meta: tuple = (), priv_shapes: tuple = ()):
         """Jitted recovery pass for one (shape, mode) signature.
+
+        The program takes ``(params, tokens, ck, cv, src, shared_mask,
+        length, n_sel_real, *priv_args)``: ``S`` and ``n_sel`` are the
+        (bucketed) length and budget it runs at, ``length`` and
+        ``n_sel_real`` the real ones, as operands.
 
         ``priv_mode`` is one of:
           "none"  — no private caches
           "dense" — trailing args (pk [N,L,S,KV,hd], pv, psrc [N,S],
                     pmask [S]) as pre-densified tensors
           "paged" — the zero-densify fast path: same trailing args as
-                    below, but the pool + page tables flow into
-                    ``pic_prefill`` as a :class:`PagedHistory` and each
-                    layer's attention reads its pages at the point of
-                    use — no ``_densify_paged``, no dense per-request
-                    private cache anywhere in the jit
+                    below plus the span length as an operand, but the
+                    pool + page tables flow into ``pic_prefill`` as a
+                    :class:`PagedHistory` and each layer's attention
+                    reads its pages at the point of use — no
+                    ``_densify_paged``, no dense per-request private
+                    cache anywhere in the jit
           "paged_densify" — the parity oracle: identical inputs, but the
                     pages are gathered into dense ``[N, L, S, KV, hd]``
                     tensors up front (``_densify_paged``) and recovery
@@ -282,41 +291,56 @@ class KVCollector:
 
         For both paged modes the trailing args are (pool_k
         [L,P,bt,KV,hd], pool_v, page_idx [N,nbh], [tail_k, tail_v,]
-        psrc, pmask) and ``paged_meta = (start, span_len, has_tail)``
-        are the static placement params.
+        psrc, pmask); ``paged_meta`` is ``(start, has_tail)`` for
+        "paged", whose span length follows as the last operand, and
+        ``(start, span_len, has_tail)`` for the oracle.
+
+        Programs are keyed by that signature, the shapes of the private
+        args (``priv_shapes``; a family pool's page count is one) and
+        every static the builder closes over, so engines in one process
+        share them and each key is one compiled program.
         """
+        cfg, check_layer, shard = self.cfg, self.check_layer, self.shard
+        block_select = self.block_select
+        pooled = share and self.pooled_selection
+        recover = pic_prefill
+
         def build():
-            def run(params, tokens, ck, cv, src, shared_mask, *args):
+            def run(params, tokens, ck, cv, src, shared_mask, length,
+                    n_sel_real, *args):
                 pk = pv = psrc = pmask = None
                 hist = None
                 if priv_mode == "dense":
                     pk, pv, psrc, pmask = args
                 elif priv_mode in ("paged", "paged_densify"):
-                    start, span_len, has_tail = paged_meta
+                    start, has_tail = paged_meta[0], paged_meta[-1]
                     pool_k, pool_v, page_idx = args[:3]
                     tail_k, tail_v = args[3:5] if has_tail else (None, None)
-                    psrc, pmask = args[5:] if has_tail else args[3:]
                     if priv_mode == "paged":
+                        *_, psrc, pmask, span_len = args
                         hist = PagedHistory(
                             pool_k=pool_k, pool_v=pool_v, page_idx=page_idx,
                             src=psrc, start=start, span_len=span_len,
                             tail_k=tail_k, tail_v=tail_v)
                         psrc = None
                     else:
+                        psrc, pmask = args[-2:]
                         pk, pv = _densify_paged(
                             pool_k, pool_v, page_idx, tail_k, tail_v,
-                            S=tokens.shape[1], start=start, span_len=span_len)
-                return pic_prefill(
-                    params, self.cfg, tokens, ck, cv, src, shared_mask,
+                            S=tokens.shape[1], start=start,
+                            span_len=paged_meta[1])
+                return recover(
+                    params, cfg, tokens, ck, cv, src, shared_mask,
                     n_sel, priv_k=pk, priv_v=pv, priv_src=psrc,
                     priv_mask=pmask, priv_hist=hist,
-                    check_layer=self.check_layer,
-                    pooled_selection=share and self.pooled_selection,
-                    block_select=self.block_select, shard=self.shard)
+                    check_layer=check_layer, pooled_selection=pooled,
+                    block_select=block_select, shard=shard,
+                    length=length, n_sel_real=n_sel_real)
             return run
         return self.programs.get_jit(
             "collective_recover" if share else "serial_recover",
-            (S, n_sel, priv_mode, paged_meta), build)
+            (S, n_sel, priv_mode, paged_meta, priv_shapes, cfg,
+             check_layer, block_select, pooled, shard, recover), build)
 
     @staticmethod
     def _priv_args(priv, paged_attention: bool = True) -> Tuple[str, tuple, tuple]:
@@ -336,8 +360,10 @@ class KVCollector:
             if has_tail:
                 args += (priv.tail_k, priv.tail_v)
             args += (priv.src, priv.mask)
-            fast = paged_attention and priv.fast_path_ok()
-            return ("paged" if fast else "paged_densify", args,
+            if paged_attention and priv.fast_path_ok():
+                return ("paged", args + (np.int32(priv.span_len),),
+                        (priv.start, has_tail))
+            return ("paged_densify", args,
                     (priv.start, priv.span_len, has_tail))
         return "dense", tuple(priv), ()
 
@@ -353,6 +379,9 @@ class KVCollector:
         n_sel: int,
         priv=None,
         paged_attention: bool = True,
+        *,
+        length: Optional[int] = None,
+        n_sel_padded: Optional[int] = None,
     ) -> CollectiveResult:
         """One collective recovery pass for the whole round group (the T3
         path of Fig. 7): ONE RoPE alignment of the group-shared blocks and
@@ -386,26 +415,34 @@ class KVCollector:
                        (``identity_span_src`` fails) — a ``PagedPrivate``
                        is gathered dense inside the jit instead
                        (``_densify_paged``, the parity oracle).
+          length:      the real prompt length when every per-position
+                       input is right-padded past it (the serving path's
+                       bucketed form, ``pic.bucket_len``); default S.
+          n_sel_padded: the budget the program runs at, an upper bound
+                       of ``n_sel`` over the bucket; default ``n_sel``.
 
         Returns a :class:`CollectiveResult` whose ``pic`` holds the
         recovered caches ``[L, N, S, KV, hd]`` and last-token logits, and
         whose ``plan`` carries the Master choice + per-request deviations
         into Diff-Aware Storage. Outputs are bit-identical across the
         dense and paged ``priv`` forms (pure data movement either way)
-        and to per-request :meth:`serial_reuse` (paper §6.6).
+        and to per-request :meth:`serial_reuse` (paper §6.6). The
+        ``pic`` arrays keep the padded length; the plan's selections are
+        trimmed to the real ``n_sel``.
         """
         N, S = tokens.shape
         self.align_passes += 1
         priv_mode, args, paged_meta = self._priv_args(priv, paged_attention)
-        res = self._runner(S, n_sel, True, priv_mode, paged_meta)(
+        res = self._runner(S, n_sel_padded or n_sel, True, priv_mode,
+                           paged_meta, tuple(np.shape(a) for a in args))(
             self.params, tokens, cached_k, cached_v, src_pos, shared_mask,
-            *args)
+            np.int32(length or S), np.int32(n_sel), *args)
         dev = np.asarray(jnp.sum(
             jnp.where(shared_mask[None], res.deviation, 0.0), axis=1))
         master = int(np.argmin(dev))  # closest to the group's common structure
-        plan = ReusePlan(list(request_ids), master,
-                         np.asarray(res.sel_idx[0]), dev, S, n_sel,
-                         sel_idx_all=np.asarray(res.sel_idx))
+        sel_all = np.asarray(res.sel_idx)[:, :n_sel]
+        plan = ReusePlan(list(request_ids), master, sel_all[0], dev,
+                         length or S, n_sel, sel_idx_all=sel_all)
         return CollectiveResult(plan, res, priv_mode)
 
     # ------------------------------------------------------------------
@@ -419,26 +456,35 @@ class KVCollector:
         shared_mask: jax.Array,
         n_sel: int,
         priv=None,
+        *,
+        length: Optional[int] = None,
+        n_sel_padded: Optional[int] = None,
     ) -> List[PICResult]:
         """Per-request baseline (T2 path): N independent reuse passes, each
         repeating RoPE alignment and important-position selection.
 
-        Same contracts as :meth:`collective_reuse`; returns one
-        :class:`PICResult` per request (each with B=1 leading axes). A
+        Same contracts as :meth:`collective_reuse` (``length`` and
+        ``n_sel_padded`` included); returns one :class:`PICResult` per
+        request (each with B=1 leading axes, at the padded length). A
         :class:`PagedPrivate` ``priv`` is densified up front via its
         oracle — the baseline deliberately pays the full per-request
         materialization the collective paged path avoids."""
+        N, S = tokens.shape
         if isinstance(priv, PagedPrivate):
-            priv = priv.materialize(tokens.shape[1])
+            priv = priv.materialize(S)
         out = []
-        run = self._runner(tokens.shape[1], n_sel, False,
-                           "none" if priv is None else "dense")
-        self.align_passes += tokens.shape[0]
-        for i in range(tokens.shape[0]):
+        run = self._runner(
+            S, n_sel_padded or n_sel, False,
+            "none" if priv is None else "dense",
+            priv_shapes=() if priv is None else tuple(
+                np.shape(a) for a in priv))
+        self.align_passes += N
+        for i in range(N):
             args = ()
             if priv is not None:
                 pk, pv, psrc, pmask = priv
                 args = (pk[i : i + 1], pv[i : i + 1], psrc[i : i + 1], pmask)
             out.append(run(self.params, tokens[i : i + 1], cached_k, cached_v,
-                           src_pos, shared_mask, *args))
+                           src_pos, shared_mask, np.int32(length or S),
+                           np.int32(n_sel), *args))
         return out

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.pic import bucket_len
 from repro.models.layers import rope_shift
 
 BLOCK_TOKENS = 32
@@ -30,6 +31,16 @@ def _pad_to_blocks(x: jax.Array, bt: int) -> jax.Array:
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
     return x
+
+
+@jax.jit
+def take_blocks(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """Blocks ``idx`` (int32 [n], in range) of a blocked array
+    ``[L, nb, ...]``: ``x[:, idx]`` as one program per (shape, n). How
+    many blocks a mirror's diff holds follows from the data, so each new
+    count compiles; eager indexing would compile (and run) several small
+    programs for it besides the gather."""
+    return x[:, idx]
 
 
 @dataclass
@@ -187,6 +198,14 @@ def build_round_family(
             a = jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         return a.reshape(N, L, nb, bt, KV, hd)
 
+    # one mirror's blocks at a time, padded to the round programs' bucket:
+    # the diff-row gathers then follow the bucket and the row count alone,
+    # not the round length
+    grow = ((0, 0), (0, bucket_len(S, bt) // bt - nb), (0, 0), (0, 0), (0, 0))
+
+    def rows(b, idx):
+        return take_blocks(jnp.pad(b, grow) if grow[1][1] else b, idx)
+
     kb, vb = blockify(ks), blockify(vs)
     dk = jnp.abs(kb - kb[master_idx]).max(axis=(1, 3, 4, 5))   # [N, nb]
     dv = jnp.abs(vb - vb[master_idx]).max(axis=(1, 3, 4, 5))
@@ -200,7 +219,7 @@ def build_round_family(
         diff = MirrorDiff(
             rid=rid, master_rid=master.rid,
             block_idx=idx,
-            k_vals=kb[i][:, idx], v_vals=vb[i][:, idx],
+            k_vals=rows(kb[i], idx), v_vals=rows(vb[i], idx),
             old_pos=master.positions, new_pos=master.positions,
             seq_len=S, block_tokens=bt)
         handles.append(MirrorHandle(master, diff))
@@ -252,7 +271,8 @@ def trim_family(handles: Sequence[MirrorHandle],
         out.append(MirrorHandle(tm, MirrorDiff(
             rid=d.rid, master_rid=d.master_rid,
             block_idx=(bidx[keep] - b0).astype(np.int32),
-            k_vals=d.k_vals[:, keep], v_vals=d.v_vals[:, keep],
+            k_vals=take_blocks(d.k_vals, keep.astype(np.int32)),
+            v_vals=take_blocks(d.v_vals, keep.astype(np.int32)),
             old_pos=np.asarray(d.old_pos[start:seq_len], np.int32),
             new_pos=np.asarray(d.new_pos[start:seq_len], np.int32),
             seq_len=seq_len - start, block_tokens=bt)))

@@ -26,6 +26,15 @@ one shared RoPE alignment of the shared blocks and one batched
 important-position pass identify each request's positions simultaneously,
 so the per-round reuse overhead is paid once (paper §4.2). Outputs are
 bit-identical to per-request recovery (paper §6.6).
+
+A round's prompts grow with the conversation, so the serving engine runs
+recovery (and decode) at a *bucketed* length, :func:`bucket_len`: the
+prompt is right-padded to a whole number of ``BUCKET_BLOCKS`` KV blocks
+and :func:`pic_prefill` takes the real length and the real selection
+budget as operands. Padded positions are neither cached nor selectable,
+and causal attention keeps them out of every real position, so the
+recomputed blocks and the real rows of the result are those of the
+unpadded pass.
 """
 from __future__ import annotations
 
@@ -49,6 +58,21 @@ from repro.models.layers import (
 from repro.models.transformer import _logits
 
 BIG = 1.0e30
+
+#: the serving path pads a prompt up to a whole number of this many KV
+#: blocks, so every prompt length of a bucket shares one program
+BUCKET_BLOCKS = 8
+
+
+def bucket_len(S: int, block_tokens: int) -> int:
+    """Length the round programs run a prompt of ``S`` tokens at: ``S``
+    rounded up to a multiple of ``BUCKET_BLOCKS`` blocks of
+    ``block_tokens``. Token-level selection (``block_tokens`` 0) has no
+    block to bucket by and runs at ``S``."""
+    if not block_tokens:
+        return S
+    unit = BUCKET_BLOCKS * block_tokens
+    return -(-S // unit) * unit
 
 
 @dataclass
@@ -76,8 +100,9 @@ class PagedHistory:
 
     Fields: pools ``[L, P, bt, KV, hd]``; ``page_idx`` int32 [B, nbh];
     ``src`` int32 [B, S] (used for the tail rotation only);
-    ``start``/``span_len`` static placement of the paged span; tails
-    ``[B, L, T, KV, hd]`` or None.
+    ``start`` static placement of the paged span and ``span_len`` its
+    length (static, or a traced int32 scalar under a page table padded
+    past the span); tails ``[B, L, T, KV, hd]`` or None.
     """
 
     pool_k: jax.Array
@@ -85,7 +110,7 @@ class PagedHistory:
     page_idx: jax.Array
     src: jax.Array
     start: int
-    span_len: int
+    span_len: "int | jax.Array"
     tail_k: Optional[jax.Array] = None
     tail_v: Optional[jax.Array] = None
 
@@ -141,12 +166,13 @@ def _fresh_block(h, p, cfg, positions, cos, sin, shard):
 
 
 def _selective_block(h_sel, p, cfg, *, sel_pos, cos_sel, sin_sel,
-                     k_base, v_base, sel_idx, shard):
+                     k_base, v_base, sel_dst, shard):
     """Recompute one layer at the selected positions only.
 
     h_sel: [B, n, D]; k_base/v_base: [B, S, KV, hd] (aligned cache); the
-    fresh K/V of the selected tokens are scattered into the base before
-    attention. Returns (h_sel', k_merged, v_merged).
+    fresh K/V of the selected tokens are scattered into the base at
+    ``sel_dst`` before attention (an index past ``S`` writes nothing).
+    Returns (h_sel', k_merged, v_merged).
     """
     B, n, D = h_sel.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
@@ -166,10 +192,10 @@ def _selective_block(h_sel, p, cfg, *, sel_pos, cos_sel, sin_sel,
     k = apply_rope(k, cos_sel, sin_sel)
 
     def scatter(base_b, vals_b, idx_b):
-        return base_b.at[idx_b].set(vals_b)
+        return base_b.at[idx_b].set(vals_b, mode="drop")
 
-    k_merged = jax.vmap(scatter)(k_base, k, sel_idx)
-    v_merged = jax.vmap(scatter)(v_base, v, sel_idx)
+    k_merged = jax.vmap(scatter)(k_base, k, sel_dst)
+    v_merged = jax.vmap(scatter)(v_base, v, sel_dst)
 
     S = k_base.shape[1]
     kv_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -198,32 +224,43 @@ def _paged_base_layer(ph: PagedHistory, aligned_k: jax.Array,
     densify (``[B, L, S, ...]``) of the pre-paged path never happens;
     the per-layer read is the same stream the paged flash kernel issues
     from its BlockSpec index map on TPU.
+
+    ``ph.span_len`` may be traced: the page table then covers at least
+    the span (padded rows read any page and are masked off) and the tail
+    lands at a traced offset, so one program serves every span length.
     """
     L, _, bt, KV, hd = ph.pool_k.shape
+    S = aligned_k.shape[1]
     nbh = ph.page_idx.shape[1]
     T = ph.tail_len
     s0, ts = ph.start, ph.start + ph.span_len
+    width = min(nbh * bt, S - s0)         # page rows placed from s0 on
+    pos = jnp.arange(S, dtype=jnp.int32)
+    in_span = ((pos >= s0) & (pos < ts))[None, :, None, None]
     al_tail_k = None
     if T:
         # the tail is fresh decode content cached at last round's
         # positions — the only part of the paged history that rotates
-        tail_tgt = jnp.arange(ts, ts + T, dtype=jnp.int32)
+        tail_tgt = ts + jnp.arange(T, dtype=jnp.int32)
+        tail_src = jax.lax.dynamic_slice_in_dim(ph.src, ts, T, axis=1)
         al_tail_k = jax.vmap(  # over batch
             lambda tk, srow: align_cached_keys(tk, srow, tail_tgt, theta)
-        )(ph.tail_k, ph.src[:, ts : ts + T])
+        )(ph.tail_k, tail_src)
+
+    def read_span(pool):
+        x = pool[ph.page_idx].reshape(B, nbh * bt, KV, hd)[:, :width]
+        return jnp.pad(x, ((0, 0), (s0, S - s0 - width), (0, 0), (0, 0)))
 
     def base_layer(l):
         k_l = jnp.broadcast_to(aligned_k[l][None], (B,) + aligned_k.shape[1:])
         v_l = jnp.broadcast_to(shared_v[l][None], k_l.shape)
-        span_k = ph.pool_k[l][ph.page_idx].reshape(
-            B, nbh * bt, KV, hd)[:, : ph.span_len]
-        span_v = ph.pool_v[l][ph.page_idx].reshape(
-            B, nbh * bt, KV, hd)[:, : ph.span_len]
-        k_l = k_l.at[:, s0:ts].set(span_k)
-        v_l = v_l.at[:, s0:ts].set(span_v)
+        k_l = jnp.where(in_span, read_span(ph.pool_k[l]), k_l)
+        v_l = jnp.where(in_span, read_span(ph.pool_v[l]), v_l)
         if T:
-            k_l = k_l.at[:, ts : ts + T].set(al_tail_k[:, l])
-            v_l = v_l.at[:, ts : ts + T].set(ph.tail_v[:, l])
+            k_l = jax.lax.dynamic_update_slice_in_dim(
+                k_l, al_tail_k[:, l], ts, axis=1)
+            v_l = jax.lax.dynamic_update_slice_in_dim(
+                v_l, ph.tail_v[:, l], ts, axis=1)
         return k_l, v_l
 
     return base_layer
@@ -248,6 +285,8 @@ def pic_prefill(
     pooled_selection: bool = False,
     block_select: int = 0,
     shard=_noshard,
+    length=None,              # real prompt length (int32 scalar) <= S
+    n_sel_real=None,          # real budget (int32 scalar) <= n_sel
 ) -> PICResult:
     """CacheBlend-style recovery for a group of requests (see module doc).
 
@@ -270,6 +309,15 @@ def pic_prefill(
     reach attention without a dense per-request private cache ever being
     materialized. The two forms are bit-identical (pure data movement +
     a skipped identity rotation).
+
+    Bucketed form: ``tokens`` and every per-position input may be
+    right-padded past the real prompt, whose ``length`` is then passed
+    as an operand, and ``n_sel`` may be an upper bound over the bucket
+    with the real budget in ``n_sel_real``. Padded positions are neither
+    cached nor selectable; the budget's surplus slots repeat the last
+    real position, and their rows are never written back. The first
+    ``n_sel_real`` entries of ``sel_idx``, the logits and the recovered
+    KV at ``[:length]`` are then those of the unpadded pass.
     """
     assert cfg.has_attention and not cfg.has_ssm, \
         "PIC applies to attention KV caches only (see DESIGN.md §5)"
@@ -280,6 +328,8 @@ def pic_prefill(
     theta = cfg.rope_theta
     tgt_pos = jnp.arange(S, dtype=jnp.int32)
     is_cached = shared_mask if priv_mask is None else (shared_mask | priv_mask)
+    last = S - 1 if length is None else length - 1    # last real position
+    n_real = n_sel if n_sel_real is None else n_sel_real
 
     # ---- 1. alignment ------------------------------------------------------
     # shared blocks: ONE rotation for the whole group. ``base_layer(l)``
@@ -326,7 +376,10 @@ def pic_prefill(
     deviation = jnp.sum(dk * dk, axis=(-1, -2))            # [B, S]
     deviation = jnp.where(is_cached[None], deviation, 0.0)
     scores = jnp.where(is_cached[None], deviation, BIG)    # fresh always win
-    scores = scores.at[:, S - 1].add(2 * BIG)              # last token always
+    # padding is neither cached nor fresh: it scores nothing, and the
+    # last real token is always selected (its row gives the logits)
+    scores = jnp.where(tgt_pos <= last, scores, 0.0)
+    scores = scores + jnp.where(tgt_pos == last, 2 * BIG, 0.0)
     if pooled_selection:
         # beyond-paper option: ONE pooled set for the whole group. Aligns
         # every mirror's diff blocks with the master's recomputed blocks
@@ -334,6 +387,8 @@ def pic_prefill(
         # PIC output equivalence. Off by default (paper semantics).
         scores = jnp.broadcast_to(
             jnp.mean(scores, axis=0, keepdims=True), scores.shape)
+    # surplus slots of a bucketed budget get an index past every real one
+    # (so they sort last), then repeat the last real position
     if block_select:
         bt = block_select
         assert n_sel % bt == 0, "n_sel must be a multiple of block_select"
@@ -341,14 +396,22 @@ def pic_prefill(
         pad = (-S) % bt
         bscores = jnp.pad(scores, ((0, 0), (0, pad))).reshape(B, -1, bt)
         bscores = jnp.sum(bscores, axis=-1)                # [B, nb]
-        _, bidx = jax.lax.top_k(bscores, nb_sel)           # [B, nb_sel]
+        nb = bscores.shape[1]
+        whole_pad = jnp.arange(nb) * bt > last             # never selected
+        _, bidx = jax.lax.top_k(jnp.where(whole_pad, -BIG, bscores), nb_sel)
+        bidx = jnp.where(jnp.arange(nb_sel) < n_real // bt, bidx, nb)
+        bidx = jnp.sort(bidx, axis=-1)                     # [B, nb_sel]
         idx = (bidx[:, :, None] * bt
                + jnp.arange(bt, dtype=bidx.dtype)[None, None, :])
-        idx = jnp.minimum(idx.reshape(B, n_sel), S - 1)    # clip padded tail
-        sel_idx = jnp.sort(idx, axis=-1)
+        # clips a partial last block and the surplus slots
+        sel_idx = jnp.minimum(idx.reshape(B, n_sel), last)
     else:
-        _, idx = jax.lax.top_k(scores, n_sel)              # per-request pass
-        sel_idx = jnp.sort(idx, axis=-1)
+        _, idx = jax.lax.top_k(jnp.where(tgt_pos <= last, scores, -BIG),
+                               n_sel)                      # per-request pass
+        idx = jnp.where(jnp.arange(n_sel) < n_real, idx, S)
+        sel_idx = jnp.minimum(jnp.sort(idx, axis=-1), last)
+    # rows written back into the KV: the real slots only
+    sel_dst = jnp.where(jnp.arange(n_sel) < n_real, sel_idx, S)
 
     # ---- 4. selective recomputation through the remaining layers ---------
     # one layer at a time: each layer's base KV comes from base_layer(l)
@@ -358,7 +421,8 @@ def pic_prefill(
     rec_ks, rec_vs = [], []
 
     def scatter_rows(base, vals, idx):
-        return jax.vmap(lambda b, v_, i: b.at[i].set(v_))(base, vals, idx)
+        return jax.vmap(lambda b, v_, i: b.at[i].set(v_, mode="drop"))(
+            base, vals, idx)
 
     # layers <= check: keep aligned values except at selected rows (fresh)
     for l in range(check_layer + 1):
@@ -367,8 +431,8 @@ def pic_prefill(
             fresh_k[l], sel_idx[:, :, None, None], axis=1)
         sel_v = jnp.take_along_axis(
             fresh_v[l], sel_idx[:, :, None, None], axis=1)
-        rec_ks.append(scatter_rows(bk_l, sel_k, sel_idx))
-        rec_vs.append(scatter_rows(bv_l, sel_v, sel_idx))
+        rec_ks.append(scatter_rows(bk_l, sel_k, sel_dst))
+        rec_vs.append(scatter_rows(bv_l, sel_v, sel_dst))
 
     sel_pos = jnp.take_along_axis(positions, sel_idx, axis=1)  # [B, n_sel]
     cos_sel, sin_sel = rope_cos_sin(sel_pos, cfg.resolved_head_dim, theta)
@@ -379,14 +443,14 @@ def pic_prefill(
         h_sel, k_m, v_m = _selective_block(
             h_sel, _layer(params, l), cfg, sel_pos=sel_pos,
             cos_sel=cos_sel, sin_sel=sin_sel,
-            k_base=bk_l, v_base=bv_l, sel_idx=sel_idx, shard=shard)
+            k_base=bk_l, v_base=bv_l, sel_dst=sel_dst, shard=shard)
         rec_ks.append(k_m)
         rec_vs.append(v_m)
     rec_k = jnp.stack(rec_ks)
     rec_v = jnp.stack(rec_vs)
 
     # ---- 5. last-token logits --------------------------------------------
-    is_last = sel_idx == (S - 1)                            # [B, n_sel]
+    is_last = sel_idx == last                               # [B, n_sel]
     row = jnp.argmax(is_last, axis=1)
     h_last = jnp.take_along_axis(h_sel, row[:, None, None], axis=1)
     logits = _logits(params, cfg, h_last, shard)[:, 0]
@@ -400,18 +464,22 @@ def n_sel_for(layout_fresh: int, n_cached: int, ratio: float) -> int:
     return layout_fresh + max(1, int(math.ceil(ratio * n_cached)))
 
 
-def n_sel_for_blocks(fresh_mask, bt: int, ratio: float) -> int:
+def n_sel_for_blocks(fresh_mask, bt: int, ratio: float,
+                     length: int = 0) -> int:
     """Static selected-set size for block-granular selection.
 
     Counts the blocks containing any fresh token (always selected) plus
-    ``ratio`` of the pure-cached blocks, and returns it in tokens.
+    ``ratio`` of the pure-cached blocks, and returns it in tokens. With
+    ``length`` past the prompt, the blocks of the padding count as
+    cached: the budget of a prompt padded to ``length``, an upper bound
+    over every prompt of that bucket with the same fresh blocks.
     """
     import math
 
     import numpy as np
     fm = np.asarray(fresh_mask, bool).copy()
     S = fm.shape[0]
-    pad = (-S) % bt
+    pad = max(length - S, (-S) % bt)
     fm = np.pad(fm, (0, pad))
     # block containing the last token is always selected (logits)
     fm[S - 1] = True

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.collector import KVCollector
+from repro.core.pic import bucket_len
 from repro.core.rounds import (
     AgentState,
     AllGather,
@@ -77,8 +78,8 @@ class DecodeState:
     :meth:`ServingEngine._decode_paged`); the continuous engine
     (``serving/loop``) holds several of these open at once and advances
     each on its scheduler tick. Both paths share the program cache keyed
-    by (kind, N, S+G), so an interleaved decode compiles and computes
-    exactly what the synchronized loop does — this is the mechanism
+    by (kind, N, bucketed S+G), so an interleaved decode compiles and
+    computes exactly what the synchronized loop does — this is the mechanism
     behind the bit-exact oracle relationship.
     """
 
@@ -225,80 +226,76 @@ class ServingEngine:
     def _decode_begin(self, first_logits, prefill_cache: dict, N: int,
                       S: int, gaids: List[str], use_paged: bool,
                       gid: str, round_idx: int) -> DecodeState:
-        """Build the decode cache, get the step program (keyed by (kind,
-        N, S+G)) and take the first greedy token from the recovery
-        logits — everything up to (but not including) the first decode
-        step. The returned
+        """Build the decode cache, get the step program and take the
+        first greedy token from the recovery logits — everything up to
+        (but not including) the first decode step. The returned
         :class:`DecodeState` is then advanced by :meth:`_decode_advance`
-        one model step at a time and closed by :meth:`_decode_finish`."""
+        one model step at a time and closed by :meth:`_decode_finish`.
+
+        The step program is keyed by (kind, N, bucketed total): the cache
+        holds ``bucket_len(S) + G`` positions whatever the prompt length
+        of the bucket. ``length`` stays S, so generated token t lands at
+        S + t; every position from S on starts invalid, and the padding
+        past S + G is never written or attended to. ``prefill_cache``
+        holds S positions, or the bucketed length of a padded recovery."""
         cfg, G = self.cfg, self.gen_len
-        total = S + G
         bt = self.block_select
+        total = bucket_len(S, bt) + G
+        # position tables built on the host: no device program per length
+        kv_pos = np.zeros((N, total), np.int32)
+        kv_pos[:, :S] = np.arange(S, dtype=np.int32)
+        kv_valid = np.zeros((N, total), bool)
+        kv_valid[:, :S] = True
+        cache = {"length": jnp.asarray(np.full((N,), S, np.int32))}
+        if "k" in prefill_cache:
+            cache.update(kv_pos=jnp.asarray(kv_pos),
+                         kv_valid=jnp.asarray(kv_valid))
         if use_paged:
             # the recovered prefill KV becomes each agent's sealed pages;
-            # gen pages start zeroed (the dense loop's jnp.pad by G,
+            # the pages past it start zeroed (the dense loop's jnp.pad,
             # page-shaped)
-            nb_s, nb_g = S // bt, G // bt
-            nbt = nb_s + nb_g
+            nbt = total // bt
             k, v = prefill_cache["k"], prefill_cache["v"]
-            L, _, _, KV, hd = k.shape
+            L, _, Sk, KV, hd = k.shape
 
             def to_pool(x):
-                x = x.reshape(L, N, nb_s, bt, KV, hd)
-                x = jnp.pad(x, ((0, 0), (0, 0), (0, nb_g),
+                x = x.reshape(L, N, Sk // bt, bt, KV, hd)
+                x = jnp.pad(x, ((0, 0), (0, 0), (0, nbt - Sk // bt),
                                 (0, 0), (0, 0), (0, 0)))
                 return x.reshape(L, N * nbt, bt, KV, hd)
 
-            cache = {
-                "pk": to_pool(k),
-                "pv": to_pool(v),
-                "page_idx": jnp.arange(N * nbt,
-                                       dtype=jnp.int32).reshape(N, nbt),
-                "kv_pos": jnp.pad(jnp.broadcast_to(
-                    jnp.arange(S, dtype=jnp.int32)[None], (N, S)),
-                    ((0, 0), (0, G))),
-                "kv_valid": jnp.pad(jnp.ones((N, S), bool),
-                                    ((0, 0), (0, G))),
-                "length": jnp.full((N,), S, jnp.int32),
-            }
-            def build():
-                def f(params, tok, cache):
-                    logits, cache = decode_step_paged(params, cfg, tok, cache)
-                    return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            cache)
-                return f
-            step = self.programs.get_jit("decode_step_paged", (N, total),
-                                         build)
+            cache.update(pk=to_pool(k), pv=to_pool(v), page_idx=jnp.asarray(
+                np.arange(N * nbt, dtype=np.int32).reshape(N, nbt)))
+            name, step_fn = "decode_step_paged", decode_step_paged
         else:
-            cache = {"length": jnp.full((N,), S, jnp.int32)}
             if "k" in prefill_cache:
                 k, v = prefill_cache["k"], prefill_cache["v"]
-                cache.update({
-                    "k": jnp.pad(k, ((0, 0), (0, 0), (0, G),
-                                     (0, 0), (0, 0))),
-                    "v": jnp.pad(v, ((0, 0), (0, 0), (0, G),
-                                     (0, 0), (0, 0))),
-                    "kv_pos": jnp.pad(jnp.broadcast_to(
-                        jnp.arange(S, dtype=jnp.int32)[None], (N, S)),
-                        ((0, 0), (0, G))),
-                    "kv_valid": jnp.pad(jnp.ones((N, S), bool),
-                                        ((0, 0), (0, G))),
-                })
+                pad = ((0, 0), (0, 0), (0, total - k.shape[2]),
+                       (0, 0), (0, 0))
+                cache.update(k=jnp.pad(k, pad), v=jnp.pad(v, pad))
             for key_ in ("ssm", "conv"):
                 if key_ in prefill_cache:
                     cache[key_] = prefill_cache[key_]
-            def build():
-                def f(params, tok, cache):
-                    logits, cache = decode_step(params, cfg, tok, cache)
-                    return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            cache)
-                return f
-            step = self.programs.get_jit("decode_step_dense", (N, total),
-                                         build)
+            name, step_fn = "decode_step_dense", decode_step
+
+        def build():
+            def f(params, tok, cache):
+                logits, cache = step_fn(params, cfg, tok, cache)
+                return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                        cache)
+            return f
+        step = self.programs.get_jit(name, (N, total, cfg, step_fn), build)
         tok = jnp.argmax(first_logits, axis=-1).astype(jnp.int32)
         return DecodeState(step=step, tok=tok, cache=cache, outs=[tok],
                            gaids=list(gaids), S=S, G=G, bt=bt,
                            paged=use_paged, round_idx=round_idx, gid=gid)
+
+    def _bucket(self, rplan, S: int) -> dict:
+        """Real and padded prompt length and selection budget of a
+        batch's round programs: ``RoundStats.reuse["bucket"]`` and the
+        attributes of its ``recover`` span."""
+        return {"S": S, "S_padded": bucket_len(S, self.block_select),
+                "n_sel": rplan.n_sel, "n_sel_padded": rplan.n_sel_padded}
 
     def _decode_advance(self, st: DecodeState) -> None:
         """One greedy decode step. On the paged path, the write at
@@ -487,10 +484,11 @@ class ServingEngine:
         with tr.span("plan", gid=gid):
             rplan = self.policy.plan(ctx)
         stats.t_restore += tr.total("restore") - restored
-        with tr.span("recover", gid=gid, kind=rplan.kind,
-                     n_sel=rplan.n_sel) as sp:
+        bucket = self._bucket(rplan, S)
+        with tr.span("recover", gid=gid, kind=rplan.kind, **bucket) as sp:
             res = self.policy.recover(rplan, tokens)
         stats.t_recover += sp.dt
+        stats.merge_reuse("bucket", bucket)
         for k_, v_ in res.info.items():
             if k_ != "plan":
                 stats.merge_reuse(k_, v_)
@@ -498,8 +496,8 @@ class ServingEngine:
             stats.merge_reuse("restore", rplan.restore_info)
         if self.keep_recovered and "k" in res.cache:
             self._recovered_parts.append(
-                (np.asarray(res.cache["k"]),
-                 np.asarray(res.cache["v"]), list(layouts)))
+                (np.asarray(res.cache["k"])[:, :, :S],
+                 np.asarray(res.cache["v"])[:, :, :S], list(layouts)))
 
         # transient working set (the restore pool allocated during plan()
         # is reclaimed here, after its peak registered — same accounting

@@ -14,8 +14,8 @@ prefetch plug in per committee-round.
 Bit-exactness contract (the oracle relationship, pinned in tests): the
 continuous engine performs exactly the synchronized engine's
 computations — same prompt construction, same policy calls with the
-same ``RoundContext``, same program cache keyed by (kind, N, S+G), same
-decode step sequence per committee — merely interleaved across
+same ``RoundContext``, same program cache keyed by (kind, N, bucketed
+S+G), same decode step sequence per committee — merely interleaved across
 committees. Committees are computationally independent (disjoint
 sessions, disjoint Master families; a committee's prompts read only its
 own members' output blocks), and the pool's spill/reload seam is
@@ -288,11 +288,13 @@ class ContinuousEngine:
         for part in item.data["parts"]:
             rplan = part["rplan"]
             tokens = jnp.asarray(part["tokens"])
+            bucket = eng._bucket(rplan, tokens.shape[1])
             with eng.tracer.span("recover", round=r, gid=part["gid"],
-                                 kind=rplan.kind, n_sel=rplan.n_sel) as sp:
+                                 kind=rplan.kind, **bucket) as sp:
                 res = eng.policy.recover(rplan, tokens)
             part["res"] = res
             stats.t_recover += sp.dt
+            stats.merge_reuse("bucket", bucket)
             for k_, v_ in res.info.items():
                 if k_ != "plan":
                     stats.merge_reuse(k_, v_)

@@ -144,7 +144,12 @@ class RecoveryPlan:
     ctx: RoundContext
     prefix_len: int = 0
     n_sel: int = 0
-    assembled: Optional[tuple] = None   # (sk, sv, src, smask, priv, pmask, is_cached)
+    #: the budget the recovery program runs at: an upper bound of
+    #: ``n_sel`` over every prompt length of the bucket
+    n_sel_padded: int = 0
+    #: (sk, sv, src, smask, priv, pmask, is_cached), every per-position
+    #: array at the bucketed prompt length (``pic.bucket_len``)
+    assembled: Optional[tuple] = None
     restore_info: Optional[dict] = None # restore ledger for RoundStats.reuse
 
 
@@ -190,14 +195,15 @@ class ReusePolicy(ABC):
     def _recover_recompute(self, tokens: jax.Array) -> RecoveryResult:
         """Full batched prefill — the universal fallback path."""
         rt = self.rt
+        cfg, step_fn = rt.cfg, prefill
         N, S = tokens.shape
 
         def build():
             def f(params, toks):
-                logits, cache = prefill(params, rt.cfg, toks, max_len=S)
+                logits, cache = step_fn(params, cfg, toks, max_len=S)
                 return logits[:, -1], cache
             return f
-        run = rt.programs.get_jit("prefill", (N, S), build)
+        run = rt.programs.get_jit("prefill", (N, S, cfg, step_fn), build)
         logits, cache = jax.block_until_ready(run(rt.params, tokens))
         return RecoveryResult(logits, cache, {})
 

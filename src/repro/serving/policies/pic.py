@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.collector import PagedPrivate
-from repro.core.pic import n_sel_for_blocks
+from repro.core.pic import bucket_len, n_sel_for_blocks
 from repro.core.segments import (
     SHARED,
     PagedSegmentCacheEntry,
@@ -56,10 +56,14 @@ class PICPolicy(ReusePolicy):
         if not bool(np.asarray(smask).any() or np.asarray(pmask).any()):
             return RecoveryPlan(kind="recompute", ctx=ctx,
                                 restore_info=restore_info)
-        fresh = ~np.asarray(is_cached)
-        n_sel = n_sel_for_blocks(fresh, self.rt.block_select, self.rt.ratio)
-        return RecoveryPlan(kind="reuse", ctx=ctx, n_sel=n_sel,
-                            assembled=assembled, restore_info=restore_info)
+        S, bt, ratio = ctx.prompt_len, self.rt.block_select, self.rt.ratio
+        fresh = ~is_cached[:S]
+        return RecoveryPlan(
+            kind="reuse", ctx=ctx,
+            n_sel=n_sel_for_blocks(fresh, bt, ratio),
+            n_sel_padded=n_sel_for_blocks(fresh, bt, ratio,
+                                          length=bucket_len(S, bt)),
+            assembled=assembled, restore_info=restore_info)
 
     def _restore_histories(self, ctx: RoundContext):
         """Hook for policies whose history caches live compressed between
@@ -68,12 +72,15 @@ class PICPolicy(ReusePolicy):
         return None
 
     def _assemble_cached(self, ctx: RoundContext):
-        """Build the shared cached arrays + per-agent history caches."""
+        """Build the shared cached arrays + per-agent history caches, at
+        the bucketed prompt length: the padding is neither shared nor
+        private (``is_cached`` False, identity source positions)."""
         rt = self.rt
         cfg = rt.cfg
         layouts, aids = ctx.layouts, ctx.agent_ids
         L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.resolved_head_dim
-        S = layouts[0].length
+        S_real = layouts[0].length
+        S = bucket_len(S_real, rt.block_select)
         # cached KV in the model's dtype: the recovery pass embeds tokens
         # in shared_k's dtype, so a float32 buffer would lift a bf16 model
         # (and every cache it hands to decode) to float32
@@ -111,7 +118,8 @@ class PICPolicy(ReusePolicy):
             paged = [isinstance(e, PagedSegmentCacheEntry) for e in entries]
             if all(paged) and all(e.pool_k is entries[0].pool_k
                                   for e in entries):
-                priv = self._paged_priv(entries, hspan, S, priv_mask)
+                priv = self._paged_priv(entries, hspan, S, S_real,
+                                        priv_mask)
             else:
                 if any(paged):   # mixed family: fall back to the oracle
                     entries = [e.materialize() if isinstance(
@@ -141,17 +149,35 @@ class PICPolicy(ReusePolicy):
         return (jnp.stack(pks), jnp.stack(pvs),
                 jnp.asarray(np.stack(srcs)), jnp.asarray(priv_mask))
 
-    def _paged_priv(self, entries, hspan, S: int, priv_mask):
+    def history_cols(self, S_real: int, span_len: int, bt: int) -> int:
+        """Page-table columns of a history span of ``span_len`` tokens in
+        a prompt of ``S_real``: the pages the span could fill at the
+        prompt's bucketed length, beside the prompt's other tokens. One
+        number per bucket, so the recovery program's page tables (and
+        the family pools sized by it) do not follow the history."""
+        S = bucket_len(S_real, self.rt.block_select)
+        return -(-(S - S_real + span_len) // bt)
+
+    def _paged_priv(self, entries, hspan, S: int, S_real: int, priv_mask):
         """Paged private caches: ONE family page pool + per-agent page
         tables (plus each agent's dense output tail), gathered inside the
-        collector's jitted pass instead of here."""
+        collector's jitted pass instead of here.
+
+        The recovery program takes the span length as an operand, so its
+        shapes follow the bucket, not the history: every table gets
+        :meth:`history_cols` columns (padded columns read page 0 and are
+        masked off), and the family pool's page count is sized by the
+        same rule where the policy builds it (TokenDance)."""
         e0 = entries[0]
         span_len, T = e0.seq_len, e0.tail_len
         assert span_len + T == len(hspan), (span_len, T, len(hspan))
         for e in entries:
             assert e.seq_len == span_len and e.tail_len == T, \
                 "family entries must share the span layout"
-        rows = np.stack([np.asarray(e.page_idx) for e in entries])
+        n_cols = self.history_cols(S_real, span_len, e0.block_tokens)
+        rows = np.zeros((len(entries), n_cols), np.int32)
+        for i, e in enumerate(entries):
+            rows[i, : len(e.page_idx)] = np.asarray(e.page_idx)
         srcs = []
         for e in entries:
             s_ = np.arange(S, dtype=np.int32)
@@ -175,17 +201,24 @@ class PICPolicy(ReusePolicy):
         aids, n_sel = plan.ctx.agent_ids, plan.n_sel
         (sk, sv, src, smask, priv, pmask, _) = plan.assembled
         N, S = tokens.shape
+        # the prompts right-padded to the bucketed length of the arrays
+        Sp = sk.shape[1]
+        padded = np.zeros((N, Sp), np.int32)
+        padded[:, :S] = plan.ctx.tokens
+        tokens = jnp.asarray(padded)
+        bucket = {"length": S, "n_sel_padded": plan.n_sel_padded}
         if not self.collective and isinstance(priv, PagedPrivate):
             # the serial baseline consumes dense priv tuples only
-            priv = priv.materialize(S)
+            priv = priv.materialize(Sp)
 
         # one pass, and back once the recovered KV and the first-token
-        # logits are on the device: that is the time to first token
+        # logits are on the device: that is the time to first token. The
+        # recovered KV keeps the padded length; decode runs at it too.
         p0 = rt.collector.align_passes
         if self.collective:
             res = rt.collector.collective_reuse(
                 aids, tokens, sk, sv, src, smask, n_sel, priv,
-                paged_attention=self.paged_attention)
+                paged_attention=self.paged_attention, **bucket)
             jax.block_until_ready((res.pic.recovered_k, res.pic.logits))
             k = res.pic.recovered_k                        # [L, N, S, KV, hd]
             v = res.pic.recovered_v
@@ -195,7 +228,7 @@ class PICPolicy(ReusePolicy):
                     "priv_mode": res.priv_mode}
         else:
             results = rt.collector.serial_reuse(
-                aids, tokens, sk, sv, src, smask, n_sel, priv)
+                aids, tokens, sk, sv, src, smask, n_sel, priv, **bucket)
             jax.block_until_ready([(r.recovered_k, r.logits)
                                    for r in results])
             k = jnp.concatenate([r.recovered_k for r in results], axis=1)

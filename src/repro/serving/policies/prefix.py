@@ -66,6 +66,7 @@ class PrefixCachePolicy(ReusePolicy):
         if plan.kind == "recompute":
             return self._recover_recompute(tokens)
         rt, p = self.rt, plan.prefix_len
+        cfg, step_fn = rt.cfg, extend
         aids = plan.ctx.agent_ids
         N, S = tokens.shape
         kpre = jnp.stack([rt.sessions[a].dense_k[:, :p] for a in aids], axis=1)
@@ -83,10 +84,11 @@ class PrefixCachePolicy(ReusePolicy):
                         jnp.arange(S)[None] < p, (N, S)),
                     "length": jnp.full((N,), p, jnp.int32),
                 }
-                logits, cache = extend(params, rt.cfg, toks[:, p:], cache)
+                logits, cache = step_fn(params, cfg, toks[:, p:], cache)
                 return logits[:, -1], {"k": cache["k"], "v": cache["v"]}
             return f
-        run = rt.programs.get_jit("prefix_extend", (N, S, p), build)
+        run = rt.programs.get_jit("prefix_extend", (N, S, p, cfg, step_fn),
+                                  build)
         logits, cache = jax.block_until_ready(
             run(rt.params, tokens, kpre, vpre))
         return RecoveryResult(logits, cache, {"prefix_len": p})
@@ -97,10 +99,11 @@ class PrefixCachePolicy(ReusePolicy):
         if kv is None:
             return
         rt = self.rt
-        # dense session caches ARE this policy's storage design, so the
-        # full-cache gather (a no-op for a dense round) is intentional
-        kc, vc = kv.dense()               # [L, N, S+G, KV, hd]
+        # dense session caches ARE this policy's storage design: keep the
+        # prompt and its generated tokens (a decode at a bucketed length
+        # leaves padding past them)
         S, G = ctx.prompt_len, rt.gen_len
+        kc, vc = kv.slice(0, S + G)       # [L, N, S+G, KV, hd]
         for i, a in enumerate(ctx.agent_ids):
             s = rt.sessions[a]
             s.dense_k = kc[:, i]

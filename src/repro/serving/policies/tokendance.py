@@ -17,6 +17,7 @@ from repro.core.diff_store import (
     MasterCache,
     build_round_family,
     compression_stats,
+    take_blocks,
 )
 from repro.core.segments import PagedSegmentCacheEntry, SegmentCacheEntry, segment_hash
 from repro.serving.policies.base import (RecoveryResult, RoundContext,
@@ -184,9 +185,9 @@ class TokenDancePolicy(PICPolicy):
                        fam: Optional[tuple] = None) -> dict:
         """One page-sharing family launch; entries reference the pool.
         The family is first TRIMMED to the history span — restore covers
-        only the blocks recovery will read, so the pool holds
-        ``nbh + M*ndb_h`` pages independent of the rest of the previous
-        prompt.
+        only the blocks recovery will read, so it writes ``nbh + M*ndb_h``
+        pages independent of the rest of the previous prompt, into a pool
+        of :meth:`_bucket_pool_pages` pages where that is more.
 
         In incremental mode this full restore doubles as the pool
         BOOTSTRAP (and the fallback after an invalidation): the built
@@ -219,30 +220,33 @@ class TokenDancePolicy(PICPolicy):
             handles = trim_family(
                 [rt.sessions[a].mirror for a in mirrors_all], span_len)
             bt = handles[0].diff.block_tokens
-            n_pool = family_pool_pages(handles)
-            if not persist:
-                # claim the restore pool's pages from the manager BEFORE
-                # the launch — under pressure this evicts cold owners
-                # first — and hand the grant to the restore so it builds
-                # exactly the pages the ledger accounts
-                rt.pool_free(f"restore:family:{gid}")
-                rt.pool_alloc_tokens(f"restore:family:{gid}", n_pool * bt,
-                                     persistent=False)
-            pool_k, pool_v, page_idx = fused_restore_family_shared(
-                handles, n_pages=n_pool)
+            n_write = family_pool_pages(handles)
         else:
             # single-agent family: the pool is just the Master's blocks
             bt = rt.block_select or 32
-            mk = _pad_to_blocks(master.k[:, :span_len], bt)
-            mv = _pad_to_blocks(master.v[:, :span_len], bt)
-            nb_ = mk.shape[1] // bt
-            if not persist:
-                rt.pool_free(f"restore:family:{gid}")
-                rt.pool_alloc_tokens(f"restore:family:{gid}", nb_ * bt,
-                                     persistent=False)
-            pool_k = mk.reshape(L, nb_, bt, KV, hd)
-            pool_v = mv.reshape(L, nb_, bt, KV, hd)
-            page_idx = np.zeros((0, nb_), np.int32)
+            n_write = -(-span_len // bt)
+        # the pool holds the pages the restore writes, and has room for
+        # the pages the family can fill in this prompt's bucket
+        n_pool = max(n_write, self._bucket_pool_pages(
+            ctx, span_len, bt, len(all_members)))
+        if not persist:
+            # claim the restore pool's pages from the manager BEFORE the
+            # launch — under pressure this evicts cold owners first — and
+            # hand the grant to the restore so it builds exactly the pages
+            # the ledger accounts
+            rt.pool_free(f"restore:family:{gid}")
+            rt.pool_alloc_tokens(f"restore:family:{gid}", n_pool * bt,
+                                 persistent=False)
+        if mirrors_all:
+            pool_k, pool_v, page_idx = fused_restore_family_shared(
+                handles, n_pages=n_pool)
+        else:
+            pad = ((0, 0), (0, n_pool - n_write), (0, 0), (0, 0), (0, 0))
+            pool_k = jnp.pad(_pad_to_blocks(master.k[:, :span_len], bt)
+                             .reshape(L, n_write, bt, KV, hd), pad)
+            pool_v = jnp.pad(_pad_to_blocks(master.v[:, :span_len], bt)
+                             .reshape(L, n_write, bt, KV, hd), pad)
+            page_idx = np.zeros((0, n_write), np.int32)
         nb = -(-span_len // bt)
         master_row = np.arange(nb, dtype=np.int32)
         mirror_row = {a: i for i, a in enumerate(mirrors_all)}
@@ -281,8 +285,6 @@ class TokenDancePolicy(PICPolicy):
         # the family's shared pages are accounted ONCE, not once per
         # mirror — this is the accounting face of §4.4's page sharing
         # (the ledger entry itself was claimed before the launch above)
-        n_pool = int(pool_k.shape[1])
-        pool_bytes = 2 * pool_k.size * pool_k.dtype.itemsize
         page_b = 2 * L * bt * KV * hd * pool_k.dtype.itemsize
         return {
             "paged": True,
@@ -290,14 +292,24 @@ class TokenDancePolicy(PICPolicy):
             "n_restored": len(pending),
             "n_mirrors": len(mirrors),
             "nb": nb,                       # blocks per family member
-            "pool_pages": n_pool,           # nb + M*ndb (shared once)
+            "pool_pages": n_write,          # nb + M*ndb (shared once)
             "full_write_pages": (len(mirrors) + 1) * nb,  # un-shared cost
             "page_bytes": page_b,
-            "bytes_materialized": pool_bytes + entry_bytes,
+            "bytes_materialized": n_write * page_b + entry_bytes,
             "dense_equiv_bytes": dense_equiv,
         }
 
     # ------------------------------------------------ incremental restore
+    def _bucket_pool_pages(self, ctx: RoundContext, span_len: int,
+                           bt: int, n_members: int) -> int:
+        """Pages a family pool gets for this prompt's bucket: every
+        member's history could fill ``history_cols`` pages, and a
+        copy-on-write claims one more before it lets the page it replaces
+        go. One number per bucket, so the programs that take the pool
+        (recovery, the restore's page writes) keep one shape a bucket."""
+        return n_members * self.history_cols(ctx.prompt_len, span_len,
+                                              bt) + 1
+
     def _drop_hist_pool(self, fam: tuple) -> None:
         """Invalidate a family's cross-round pool: forget the page tables
         and release the persistent owner from every tier."""
@@ -337,9 +349,12 @@ class TokenDancePolicy(PICPolicy):
         nb_prev = pool.span_len // bt
         new_span_pages = cow_pages = cow_dedup_hits = 0
         grown0 = pool.grown_pages
+        pool.reserve(self._bucket_pool_pages(ctx, span_len, bt,
+                                             len(pool.page_tables)))
         if pend is not None:
             new_span_pages, cow_pages, cow_dedup_hits = \
                 self._apply_pending(pool, fam, master)
+        if pend is not None or pool.grown_pages != grown0:
             # capacity may have grown (or stayed put with recycled COW
             # pages) — re-account the persistent owner at its real size
             rt.pool_free(pool.owner)
@@ -472,13 +487,21 @@ class TokenDancePolicy(PICPolicy):
                 pool.page_tables[a][b] = q
                 pool.incref([q])
                 pool.decref([old])
+        n_cow = len(wp)
         if wp:
-            pool.write_pages(np.asarray(wp, np.int32),
-                             jnp.stack(wk, axis=1), jnp.stack(wv, axis=1))
+            # how many blocks a round dirties follows from the data: pad
+            # the write to the most it can be, every member's every prefix
+            # block, with repeats of its first block (the same bytes to
+            # the same page), so the stack and the scatter keep one shape
+            # a round length
+            rep = len(fam_members) * nb_prev - n_cow
+            pool.write_pages(np.asarray(wp + wp[:1] * rep, np.int32),
+                             jnp.stack(wk + wk[:1] * rep, axis=1),
+                             jnp.stack(wv + wv[:1] * rep, axis=1))
         pool.span_len = h_new
         pool.round_idx = pend.round_idx
         pool.pending = None
-        return new_span_pages, len(wp), dedup.hits
+        return new_span_pages, n_cow, dedup.hits
 
     @staticmethod
     def _family_block(master: MasterCache, diff, b: int, bt: int):
@@ -486,9 +509,11 @@ class TokenDancePolicy(PICPolicy):
         diff row when the block deviates from the Master, else the
         Master's block — exactly what a full restore writes there."""
         if diff is not None:
-            pos = np.flatnonzero(np.asarray(diff.block_idx) == b)
+            pos = np.flatnonzero(np.asarray(diff.block_idx) == b)[:1]
             if pos.size:
-                return diff.k_vals[:, int(pos[0])], diff.v_vals[:, int(pos[0])]
+                pos = pos.astype(np.int32)
+                return (take_blocks(diff.k_vals, pos)[:, 0],
+                        take_blocks(diff.v_vals, pos)[:, 0])
         return master.k[:, b * bt:(b + 1) * bt], \
             master.v[:, b * bt:(b + 1) * bt]
 

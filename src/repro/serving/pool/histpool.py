@@ -146,6 +146,13 @@ class HistoryPagePool:
         pages = [self.free_list.pop() for _ in range(n)]
         return np.asarray(pages, np.int32)
 
+    def reserve(self, n: int) -> None:
+        """Grow the arrays to ``n`` pages (nothing at or above): a caller
+        that knows how many pages its coming rounds can fill sizes the
+        pool by that, so its page count does not follow the data."""
+        if n > self.capacity:
+            self._grow(n - self.capacity)
+
     def _grow(self, add: int) -> None:
         L, _, bt, KV, hd = self.pool_k.shape
         cap = self.capacity

@@ -13,9 +13,10 @@ exactly that region.
 For the paged form a ``slice`` is an at-rest page gather — store-time
 data movement of the same class as the segment entries it feeds, sized
 to the region actually kept. The decode fast path itself never calls
-``dense()`` (the full-cache oracle gather, kept for the prefix policy
-whose design is dense session caches): that is pinned by the
-monkeypatch-spy test in tests/test_paged_decode.py.
+``dense()`` (the full-cache oracle gather): that is pinned by the
+monkeypatch-spy test in tests/test_paged_decode.py. A round cache may
+run past the prompt and its generated tokens (decode runs at a bucketed
+length), so policies slice the regions they keep.
 """
 from __future__ import annotations
 
@@ -77,8 +78,7 @@ class PagedRoundKV:
 
     def dense(self) -> Tuple[jax.Array, jax.Array]:
         """Full dense [L, N, total, KV, hd] — the oracle gather. Never
-        on the tokendance/pic fast path (spy-pinned); the prefix policy
-        uses it because dense session caches ARE its storage design."""
+        on the serving path (spy-pinned)."""
         return self.slice(0, self.total)
 
 

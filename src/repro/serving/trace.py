@@ -14,13 +14,19 @@ A span never waits for the device: device time comes from the trace.
 
 :class:`JitCache` is the one place that makes the engine's jitted
 programs. It names each program (the trace reads ``jit_<name>``), counts
-the keys it had not seen (new programs) and runs the first call of each
-inside a ``jit:<name>`` span: trace, lower, compile or load from the
-persistent cache, and dispatch.
+the keys the process had not built (new programs) and runs the first
+call of each inside a ``jit:<name>`` span: trace, lower, compile or load
+from the persistent cache, and dispatch. Built programs live in one
+table per process, so a fresh engine runs what an earlier one built
+without tracing it again. The table holds at most ``MAX_PROGRAMS`` and
+drops the least recently used first, so a conversation whose prompts
+keep growing into new buckets does not keep every executable loaded;
+:func:`clear_programs` empties it.
 """
 from __future__ import annotations
 
 import time
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -130,30 +136,59 @@ class Tracer:
         self._records.append(rec)
 
 
+#: the programs JitCaches built in this process, by (name, key), least
+#: recently used first. The key holds the shapes and every static the
+#: builder closes over, the model function it traces included; a builder
+#: closes over no engine, collector or device array (weights are
+#: arguments), so the table keeps nothing of an engine alive.
+_PROGRAMS: "OrderedDict[tuple, Callable]" = OrderedDict()
+
+#: how many programs the table keeps: several sessions' working sets (a
+#: session builds one recovery and one decode program per bucket)
+MAX_PROGRAMS = 64
+
+
+def clear_programs() -> None:
+    """Forget every program built in this process."""
+    _PROGRAMS.clear()
+
+
 class JitCache:
-    """The engine's jitted programs, by name and shape key.
+    """One engine's view of the process's jitted programs.
 
     ``get_jit(name, key, make)`` returns the program for ``(name,
-    key)``, building it on a miss: ``make()`` gives the function to
-    jit, which is renamed ``name``. A miss counts one new program under
-    ``name`` (:meth:`take_new_programs` hands the counts out) and the
-    program's first call runs inside a ``jit:<name>`` span."""
+    key)``. A program the table does not hold is built from ``make()``,
+    the function to jit, renamed ``name``; that counts one new program
+    under ``name`` (:meth:`take_new_programs` hands the counts out) and
+    its first call runs inside a ``jit:<name>`` span. A program some
+    engine of the process built already is neither counted nor timed."""
 
     def __init__(self, tracer: Optional[Tracer] = None):
         self.tracer = tracer if tracer is not None else Tracer(enabled=False)
-        self._programs: Dict[tuple, Callable] = {}
+        #: programs this cache built whose first call has not run yet
+        self._first: Dict[tuple, _FirstCall] = {}
         self._new: Dict[str, int] = {}
 
     def get_jit(self, name: str, key: tuple,
                 make: Callable[[], Callable]) -> Callable:
-        prog = self._programs.get((name, key))
-        if prog is None:
-            fn = make()
-            fn.__name__ = fn.__qualname__ = name
-            prog = _FirstCall(self.tracer, name, jax.jit(fn))
-            self._programs[(name, key)] = prog
-            self._new[name] = self._new.get(name, 0) + 1
-        return prog
+        k = (name, key)
+        first = self._first.get(k)
+        if first is not None and not first.called:
+            return first
+        prog = _PROGRAMS.get(k)
+        if prog is not None:
+            _PROGRAMS.move_to_end(k)
+            return prog
+        fn = make()
+        fn.__name__ = fn.__qualname__ = name
+        _PROGRAMS[k] = prog = jax.jit(fn)
+        while len(_PROGRAMS) > MAX_PROGRAMS:
+            _PROGRAMS.popitem(last=False)
+        self._new[name] = self._new.get(name, 0) + 1
+        self._first = {k_: f for k_, f in self._first.items()
+                       if not f.called}
+        self._first[k] = first = _FirstCall(self.tracer, name, prog)
+        return first
 
     def take_new_programs(self) -> Dict[str, int]:
         """New programs per name since the last call."""

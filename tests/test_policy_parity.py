@@ -22,6 +22,7 @@ from repro.serving import (
     TokenDancePolicy,
     get_policy,
 )
+from repro.serving.trace import clear_programs
 
 N_AGENTS = 3
 N_ROUNDS = 3
@@ -49,15 +50,19 @@ def _trace(cfg):
 
 @pytest.fixture(scope="module")
 def served(setup):
-    """Every mode served twice: legacy shim vs explicit policy object."""
+    """Every mode served twice: legacy shim vs explicit policy object.
+    Each engine starts from an empty program table, so both build (and
+    count in ``reuse["jit"]``) the programs of the trace."""
     cfg, params = setup
     out = {}
     for mode in MODES:
+        clear_programs()
         with warnings.catch_warnings():
             warnings.simplefilter("ignore", DeprecationWarning)
             legacy = MultiAgentEngine(params, cfg, mode, gen_len=GEN,
                                       recompute_ratio=0.1, keep_logits=True)
         ls = legacy.run_trace(_trace(cfg))
+        clear_programs()
         modern = ServingEngine(params, cfg, POLICY_CLASSES[mode](),
                                gen_len=GEN, recompute_ratio=0.1,
                                keep_logits=True)

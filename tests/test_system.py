@@ -9,6 +9,7 @@ from repro.configs import get_smoke_config
 from repro.core.rounds import generate_trace
 from repro.models import init_params
 from repro.serving import MultiAgentEngine, simulate_round_latency, ServiceTimes
+from repro.serving.trace import clear_programs
 
 N_AGENTS = 4
 N_ROUNDS = 3
@@ -153,9 +154,19 @@ def test_memory_fallback_degrades_service():
     assert over > fits
 
 
+@pytest.fixture
+def empty_program_table():
+    """The test builds every program it inspects, whatever other tests of
+    this worker built, and leaves none of its spied ones behind."""
+    clear_programs()
+    yield
+    clear_programs()
+
+
 @pytest.mark.parametrize("mode,kw", [("prefix", {}),
                                      ("tokendance", {"paged_decode": False})])
-def test_jitted_steps_take_weights_as_arguments(setup, monkeypatch, mode, kw):
+def test_jitted_steps_take_weights_as_arguments(setup, monkeypatch, mode, kw,
+                                                empty_program_table):
     """Every model step the engine jits receives the weights as an
     argument. A closed-over weight is lowered into the program as a dense
     constant — at full width on a chip, gigabytes of them per program."""

@@ -4,10 +4,13 @@
 Pinned here: spans nest under the right parents and carry their round
 and gather group; a disabled tracer keeps nothing; ``RoundStats.t_*``
 are the round's sums of their spans in both engines; every program is
-built once per shape key and counted once in ``reuse["jit"]``; one
-collective recovery pass runs per group and round; and tracing changes
-no served token or logit.
+built once per process and bucketed shape key and counted once in
+``reuse["jit"]``, and a second engine builds none; the process's program
+table keeps no engine alive; one collective recovery pass runs per group
+and round; and tracing changes no served token or logit.
 """
+import gc
+import weakref
 from collections import defaultdict
 
 import jax
@@ -20,7 +23,9 @@ from repro.core.rounds import SubsetGather, generate_trace
 from repro.models import init_params
 from repro.serving import (ContinuousEngine, ServingEngine,
                            TokenDancePolicy, Tracer)
-from repro.serving.trace import JitCache
+from repro.core.pic import bucket_len
+import repro.serving.trace as tracemod
+from repro.serving.trace import JitCache, clear_programs
 
 N_AGENTS = 4
 N_ROUNDS = 3
@@ -59,12 +64,21 @@ def _serve(eng, trace):
     return out
 
 
+@pytest.fixture
+def empty_program_table():
+    """Program counts start from an empty process table, whatever other
+    tests of this worker built."""
+    clear_programs()
+
+
 @pytest.fixture(scope="module")
 def served(setup):
     cfg, params = setup
+    clear_programs()
     on = _engine(params, cfg, Tracer())
+    rounds = _serve(on, _trace(cfg))
     off = _engine(params, cfg)
-    return on, _serve(on, _trace(cfg)), off, _serve(off, _trace(cfg))
+    return on, rounds, off, _serve(off, _trace(cfg))
 
 
 def test_spans_nest_under_their_parents_with_round_and_gid(served):
@@ -126,15 +140,18 @@ def test_round_stats_are_sums_of_their_spans(served, field, span):
 
 
 def test_new_programs_count_each_shape_once(served):
+    """Recovery and decode are keyed by the prompt length bucketed to 8
+    blocks: a session whose prompts grow by a block a round inside one
+    bucket builds each once, and the second group of a round reuses it."""
     _, rounds, _, _ = served
     news = [st.reuse["jit"]["new_programs"] for st, _, _ in rounds]
-    # two groups of one shape a round: each program is built and counted
-    # once, the second group reuses it
+    assert len({st.prompt_len for st, _, _ in rounds}) == N_ROUNDS
+    for st, _, _ in rounds:
+        assert [b["S_padded"] for b in st.reuse["bucket"]] == \
+            [bucket_len(st.prompt_len, 32)] * len(GROUPS) == [256, 256]
     assert news[0] == {"prefill": 1, "decode_step_paged": 1}, news[0]
-    for new in news[1:]:
-        assert new.get("collective_recover") == 1, new
-        assert new.get("decode_step_paged") == 1, new
-        assert "prefill" not in new
+    assert news[1] == {"collective_recover": 1}, news[1]
+    assert news[2:] == [{}] * (N_ROUNDS - 2), news
     # first calls ran inside jit:<name> spans, once per new program
     for (st, _, recs) in rounds:
         firsts = defaultdict(int)
@@ -144,7 +161,34 @@ def test_new_programs_count_each_shape_once(served):
         assert dict(firsts) == st.reuse["jit"]["new_programs"]
 
 
-def test_jit_cache_names_and_counts_programs():
+def test_second_engine_builds_no_program(setup, served):
+    """A fresh engine in the same process runs what the first one built:
+    no new program and no ``jit:`` span in any round."""
+    cfg, params = setup
+    again = _serve(_engine(params, cfg, Tracer()), _trace(cfg))
+    for st, _, recs in again:
+        assert st.reuse["jit"]["new_programs"] == {}
+        assert not [x for x in recs if x.name.startswith("jit:")]
+        assert [x.name for x in recs].count("recover") == len(GROUPS)
+
+
+def test_program_table_keeps_no_engine(setup, empty_program_table):
+    cfg, params = setup
+    eng = _engine(params, cfg, Tracer())
+    rounds = _serve(eng, generate_trace(
+        "generative_agents", N_AGENTS, 2, cfg.vocab_size, seed=11,
+        jitter_hist=False))
+    # it built its programs (prefill, decode, recovery) into the table
+    assert {k for st, _, _ in rounds
+            for k in st.reuse["jit"]["new_programs"]} == {
+        "prefill", "decode_step_paged", "collective_recover"}
+    ref = weakref.ref(eng)
+    del eng
+    gc.collect()
+    assert ref() is None
+
+
+def test_jit_cache_names_and_counts_programs(empty_program_table):
     tr = Tracer()
     cache = JitCache(tr)
     built = []
@@ -170,6 +214,35 @@ def test_jit_cache_names_and_counts_programs():
     cache.get_jit("double", (8,), make)
     assert cache.take_new_programs() == {"double": 1}
     assert len(built) == 2
+    # another cache of the process finds both built: nothing new, no span
+    other = JitCache(tr)
+    b = other.get_jit("double", (4,), make)
+    b(x)
+    assert other.take_new_programs() == {} and len(built) == 2
+    assert tr.drain() == []
+
+
+def test_program_table_stays_bounded(empty_program_table):
+    """A prompt that grows across many buckets keeps at most
+    ``MAX_PROGRAMS`` programs in the process table: the least recently
+    used goes first, the newest buckets stay."""
+    def make():
+        return lambda x: x + 1
+
+    cache = JitCache()
+    top = 256 * (tracemod.MAX_PROGRAMS + 8)
+    buckets = sorted({bucket_len(S, 32) for S in range(32, top, 32)})
+    assert len(buckets) > tracemod.MAX_PROGRAMS
+    for b in buckets:
+        cache.get_jit("grow", (b,), make)
+    assert cache.take_new_programs() == {"grow": len(buckets)}
+    assert len(tracemod._PROGRAMS) == tracemod.MAX_PROGRAMS
+    fresh = JitCache()
+    fresh.get_jit("grow", (buckets[-1],), make)      # kept
+    assert fresh.take_new_programs() == {}
+    fresh.get_jit("grow", (buckets[0],), make)       # dropped: built again
+    assert fresh.take_new_programs() == {"grow": 1}
+    assert len(tracemod._PROGRAMS) == tracemod.MAX_PROGRAMS
 
 
 def test_one_recovery_pass_per_collective_group_and_round(served):
