@@ -1,5 +1,6 @@
-"""The system under test, as the benchmark drives it: the only module of
-the benchmark that imports the program.
+"""The system under test, as the benchmark drives it: with the
+architecture adapters (``bench/adapters/<architecture>.py``), the only
+modules of the benchmark that import the program.
 
 A :class:`Server` is one session: a fresh ``ServingEngine`` with a
 ``TokenDancePolicy`` and every engine knob at the program's default
@@ -7,11 +8,17 @@ except the ones the traffic file fixes (topology, ``gen_len``,
 ``recompute_ratio``), stepped one round at a time with ``run_round``.
 The policy's ``plan``, ``recover`` and ``store`` are wrapped in the
 recorder's spans; decode is the interval from the return of ``recover``
-to the call of ``store``.
+to the call of ``store``. Each round's reuse ledgers go to the record as
+plain values. A traced session (``trace=True``) ends the ``plan`` span on
+the plan's device arrays, and its engine records its own spans, which go
+to the record after each round.
 """
 from __future__ import annotations
 
+import importlib.util
+import json
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -19,22 +26,35 @@ import numpy as np
 from bench.record import Batch, Recorder, Round
 from bench.traffic.generator import SessionTraffic
 
+ADAPTERS = Path(__file__).resolve().parent / "adapters"
 
-def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a Hugging Face style Qwen2
-    config."""
-    from repro.configs.base import ModelConfig
 
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return ModelConfig(
-        name=cfg["name"], arch_type="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=h,
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=d // h,
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        attn_bias=True, rope_theta=float(cfg["rope_theta"]),
-        rmsnorm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg["torch_dtype"], source=cfg["source"])
+def load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def program_config(cfg: dict, adapters: Path = ADAPTERS):
+    """The program's ``ModelConfig`` for ``cfg``, made by the adapter of
+    its architecture, ``<adapters>/<architecture>.py``."""
+    arch = cfg["architecture"]
+    path = Path(adapters) / f"{arch}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no adapter for architecture {arch!r}: {path} is missing")
+    return load_module(path, f"bench_adapter_{arch}").program_config(cfg)
+
+
+def _plain(x):
+    """``x`` as JSON values: numpy scalars and arrays become numbers and
+    lists, anything else that JSON cannot hold its ``repr``."""
+    def default(o):
+        if isinstance(o, (np.generic, np.ndarray)):
+            return o.tolist()
+        return repr(o)
+    return json.loads(json.dumps(x, default=default))
 
 
 def _plan_arrays(plan) -> list:
@@ -54,7 +74,7 @@ class Server:
 
     def __init__(self, cfg: dict, traffic: dict, weights: dict,
                  session: SessionTraffic, rec: Recorder, index: int,
-                 sync_plan: bool = False):
+                 trace: bool = False, adapters: Path = ADAPTERS):
         from repro.core.rounds import AllGatherTrace, SubsetGather
         from repro.serving import ServingEngine, TokenDancePolicy
 
@@ -65,15 +85,17 @@ class Server:
         topology = (None if topo["kind"] == "all_gather" else
                     SubsetGather.neighborhood(session.agent_ids, topo["k"]))
         self.policy = TokenDancePolicy()
+        self.tracer = _tracer() if trace else None
+        kw = {} if self.tracer is None else {"tracer": self.tracer}
         self.engine = ServingEngine(
-            weights, program_config(cfg), self.policy, topology=topology,
-            gen_len=traffic["gen_len"],
-            recompute_ratio=traffic["recompute_ratio"])
+            weights, program_config(cfg, adapters), self.policy,
+            topology=topology, gen_len=traffic["gen_len"],
+            recompute_ratio=traffic["recompute_ratio"], **kw)
         self.engine.init_agents(AllGatherTrace(
             "bench", list(session.agent_ids), [], session.vocab_size,
             session.vocab_size - 1, dict(session.init_histories),
             traffic["gen_len"]))
-        self._wrap(sync_plan)
+        self._wrap(trace)
 
     @property
     def done(self) -> bool:
@@ -130,14 +152,33 @@ class Server:
         self._state["t_round"] = t0
         with self.rec.span("round"):
             stats = self.engine.run_round(rnd)
+        t1 = time.perf_counter()
         comp = stats.reuse.get("compression") or []
         comp = comp if isinstance(comp, list) else [comp]
         self.rec.rounds.append(Round(
-            self.index, r, t0, time.perf_counter(),
-            [float(c["compression_ratio"]) for c in comp]))
+            self.index, r, t0, t1,
+            [float(c["compression_ratio"]) for c in comp],
+            _plain(stats.reuse)))
+        if self.tracer is not None:
+            self.rec.program_spans.extend(self.tracer.drain())
         self.round_idx += 1
         return stats
 
     def close(self) -> None:
-        """Drop every reference to the engine's device state."""
+        """Put the policy's own ``plan``, ``recover`` and ``store`` back,
+        which breaks the cycle the wrappers close over the policy and the
+        engine, and drop every reference to the engine's device state, so
+        that it is freed here and not at Python's next cycle collection."""
+        for name in ("plan", "recover", "store"):
+            vars(self.policy).pop(name, None)
         self.engine = self.policy = None
+
+
+def _tracer():
+    """A recording ``repro.serving.trace.Tracer``, or ``None`` for a
+    program that has no spans of its own."""
+    try:
+        from repro.serving.trace import Tracer
+    except ImportError:
+        return None
+    return Tracer()

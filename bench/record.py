@@ -1,5 +1,6 @@
 """What one run records: spans around the program's layers, the batches
-it served, compile events, and the window's bounds.
+it served, each round's reuse ledgers, compile events, the window's
+bounds, and in a traced run the program's own spans.
 
 Spans are taken on the host clock (``time.perf_counter``). In a traced run
 each span is also a ``jax.profiler.TraceAnnotation`` named ``bench:<span>``,
@@ -63,6 +64,8 @@ class Round:
     t0: float
     t1: float
     compression: List[float] = field(default_factory=list)
+    #: the program's ``RoundStats.reuse``, whole, as plain JSON values
+    reuse: dict = field(default_factory=dict)
 
 
 class Recorder:
@@ -75,6 +78,9 @@ class Recorder:
         self.rounds: List[Round] = []
         self.compile_events: List[tuple] = []   # (t, event, seconds)
         self.cache_misses: List[float] = []     # times of persistent misses
+        #: the program's span records (``repro.serving.trace.SpanRecord``:
+        #: name, t0, t1 on this clock, round, gid, attrs), in a traced run
+        self.program_spans: list = []
         self.session = 0
         self.round = -1
         self._open: Dict[str, tuple] = {}

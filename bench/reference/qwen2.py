@@ -14,12 +14,15 @@ Nothing here imports the program. The weights are made by
 (``embed``, ``blocks`` with stacked ``[L, ...]`` leaves, ``final_norm``,
 ``lm_head``), and the reference reads the same arrays.
 
-The layer comes in parts (:func:`qkv`, :func:`attend`, :func:`finish`)
-for one sequence whose queries may be any subset of its positions, so
-that ``bench/reference/tokendance.py`` can run the served semantics over
-them. Every matmul runs at ``Precision.HIGHEST`` in float32 on one layer's
-weights at a time, so a caller that scans the layers never holds a float32
-copy of more than one. ``quant`` selects a control: ``"int8"`` rounds
+The module is an architecture as ``bench/reference/tokendance.py`` replays
+one (its docstring states the contract): every layer is alike, so
+:func:`blocks` hands the replay one stacked tree, and the layer index the
+parts are given is not read. The layer comes in parts (:func:`qkv`,
+:func:`attend`, :func:`finish`) for one sequence whose queries may be any
+subset of its positions, and :func:`move_keys` moves cached keys to other
+positions. Every matmul runs at ``Precision.HIGHEST`` in float32 on one
+layer's weights at a time, so a caller that scans the layers never holds a
+float32 copy of more than one. ``quant`` selects a control: ``"int8"`` rounds
 every matrix to int8 with one absmax scale per output column (per row for
 the embedding) before use, ``"fp8"`` to float8 e4m3 with the same scales;
 everything else is unchanged.
@@ -33,16 +36,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.roofline import Layer
+
 HI = jax.lax.Precision.HIGHEST
 
 
 def dims(cfg: dict) -> dict:
-    """Model sizes from a Hugging Face style Qwen2 config."""
+    """Model sizes from a Hugging Face style Qwen2 config, and each
+    layer's shape (``layers``): dense, full causal attention."""
     h, d = cfg["num_attention_heads"], cfg["hidden_size"]
-    return dict(L=cfg["num_hidden_layers"], D=d, H=h,
-                KV=cfg["num_key_value_heads"], hd=d // h,
-                F=cfg["intermediate_size"], V=cfg["vocab_size"],
-                theta=float(cfg["rope_theta"]), eps=float(cfg["rms_norm_eps"]))
+    L, KV, hd, F = (cfg["num_hidden_layers"], cfg["num_key_value_heads"],
+                    d // h, cfg["intermediate_size"])
+    return dict(L=L, D=d, H=h, KV=KV, hd=hd, F=F, V=cfg["vocab_size"],
+                theta=float(cfg["rope_theta"]), eps=float(cfg["rms_norm_eps"]),
+                layers=(Layer(h, KV, hd, hd, F=F),) * L)
 
 
 def weight_key(seed: int) -> jax.Array:
@@ -93,6 +100,11 @@ def make_weights(seed: int, cfg: dict) -> dict:
 
 def weight_bytes(weights: dict) -> int:
     return int(sum(x.nbytes for x in jax.tree.leaves(weights)))
+
+
+def blocks(weights: dict) -> dict:
+    """The layers' weights: one tree with stacked ``[L, ...]`` leaves."""
+    return weights["blocks"]
 
 
 # --------------------------------------------------------------------------
@@ -146,7 +158,13 @@ def embed(weights, tokens, quant=None):
     return _mat(jnp.take(weights["embed"], tokens, axis=0), quant, axis=-1)
 
 
-def qkv(p, h, pos, d, quant=None):
+def move_keys(k, delta, d, li):
+    """Cached keys [T, KV, hd] of layer ``li`` moved by ``delta`` [T]
+    positions: RoPE composes, so one rotation by the difference."""
+    return rope(k, delta, d["theta"])
+
+
+def qkv(p, h, pos, d, li, quant=None):
     """RoPE'd queries [T, H, hd], keys and values [T, KV, hd] of the
     residual stream ``h`` [T, D] at positions ``pos`` [T]."""
     a = p["attn"]
@@ -161,7 +179,7 @@ def qkv(p, h, pos, d, quant=None):
     return q, k, proj("wv", "bv", d["KV"])
 
 
-def attend(q, q_pos, k, v, kv_pos, d):
+def attend(q, q_pos, k, v, kv_pos, d, li):
     """Causal grouped-query attention: query ``i`` sees every key whose
     position is at most ``q_pos[i]``. Returns [T, H * hd]."""
     T, KV, hd = q.shape[0], d["KV"], d["hd"]
@@ -174,7 +192,7 @@ def attend(q, q_pos, k, v, kv_pos, d):
     return o.reshape(T, d["H"] * hd)
 
 
-def finish(p, h, o, d, quant=None):
+def finish(p, h, o, d, li, quant=None):
     """The output projection's residual add, then the MLP's."""
     h = h + jnp.einsum("te,ed->td", o, _mat(p["attn"]["wo"], quant),
                        precision=HI)

@@ -46,6 +46,27 @@ of blocks is never read.
 
 At ``recompute_ratio`` 1 every block is recomputed and the replay is the
 plain causal forward.
+
+An architecture is a module ``bench/reference/<architecture>.py`` that
+gives the replay:
+
+* ``dims(cfg)``: a dict of hashable sizes, with ``L`` layers, ``V`` ids,
+  the cached K and V row's ``KV`` heads of ``hd`` (alike in every
+  layer), and ``layers``, each layer's ``bench.roofline.Layer``;
+* ``make_weights(seed, cfg)``, ``weight_bytes(weights)``;
+* ``blocks(weights)``: the layers' weights, either one tree of stacked
+  ``[L, ...]`` leaves, where every layer runs the same code and the
+  replay reads one layer at a time by index under ``fori_loop`` and
+  ``scan`` (the layer index it passes on may then be traced), or a list
+  of ``L`` trees, where the replay loops over the layers in Python and
+  passes each its own index, so that layers may differ in kind (a
+  leading dense layer, a window beside full attention, routed experts);
+* ``embed(weights, tokens, quant)`` and ``head(weights, h, d, quant)``;
+* per layer ``li``: ``qkv(p, h, pos, d, li, quant)``,
+  ``attend(q, q_pos, k, v, kv_pos, d, li)``,
+  ``finish(p, h, o, d, li, quant)``, and ``move_keys(k, delta, d, li)``,
+  which moves cached keys ``delta`` positions on by the layer's own
+  position encoding.
 """
 from __future__ import annotations
 
@@ -72,6 +93,19 @@ def _pad(tokens: np.ndarray, bt: int, sep: int) -> np.ndarray:
                            np.full((-len(tokens)) % bt, sep, np.int32)])
 
 
+def _layer(blocks, li):
+    """Layer ``li``'s weights: an item of a list, or a slice of stacked
+    leaves, read by index where ``li`` is traced."""
+    if isinstance(blocks, list):
+        return blocks[li]
+    if isinstance(li, int):
+        return jax.tree.map(lambda a: a[li], blocks)
+    # one layer's weights are read where they are used: a slice of the
+    # stacked layers outside the loop would copy them all
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, li, keepdims=False), blocks)
+
+
 @partial(jax.jit, static_argnames=("model", "bt", "d_items", "quant"))
 def _check(model, weights, tokens, base_k, src, cached, *, bt, d_items,
            quant):
@@ -81,14 +115,22 @@ def _check(model, weights, tokens, base_k, src, cached, *, bt, d_items,
     the cached keys [L, S, KV, hd] moved from positions ``src`` to their
     own."""
     d = dict(d_items)
+    blocks = model.blocks(weights)
     pos = jnp.arange(tokens.shape[0])
-    base_k = jax.vmap(lambda k: model.rope(k, pos - src, d["theta"]))(base_k)
+    delta = pos - src
+    if isinstance(blocks, list):
+        base_k = jnp.stack([model.move_keys(base_k[li], delta, d, li)
+                            for li in range(len(blocks))])
+    else:
+        base_k = jax.vmap(lambda k, li: model.move_keys(k, delta, d, li))(
+            base_k, jnp.arange(base_k.shape[0]))
     h = model.embed(weights, tokens, quant)
     fresh_k, fresh_v = [], []
     for li in range(CHECK_LAYER + 1):
-        p = jax.tree.map(lambda a, li=li: a[li], weights["blocks"])
-        q, k, v = model.qkv(p, h, pos, d, quant)
-        h = model.finish(p, h, model.attend(q, pos, k, v, pos, d), d, quant)
+        p = _layer(blocks, li)
+        q, k, v = model.qkv(p, h, pos, d, li, quant)
+        h = model.finish(p, h, model.attend(q, pos, k, v, pos, d, li), d, li,
+                         quant)
         fresh_k.append(k)
         fresh_v.append(v)
     dev = jnp.where(cached, jnp.sum(
@@ -104,6 +146,7 @@ def _recover(model, weights, h, fresh_k, fresh_v, base_k, base_v, blocks, *,
     """Recovered KV [L, S, KV, hd] with ``blocks`` recomputed, and the
     final residual stream at the last position."""
     d = dict(d_items)
+    layer_w = model.blocks(weights)
     pos = jnp.arange(h.shape[0])
     sel = jnp.sort((blocks[:, None] * bt + jnp.arange(bt)).reshape(-1))
     rec_k, rec_v = base_k, base_v
@@ -112,21 +155,23 @@ def _recover(model, weights, h, fresh_k, fresh_v, base_k, base_v, blocks, *,
         rec_v = rec_v.at[li, sel].set(fresh_v[li][sel])
 
     def layer(li, carry):
-        # one layer's weights are read where they are used: a slice of the
-        # stacked layers outside the loop would copy them all
         hs, rec_k, rec_v = carry
-        p = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, li, keepdims=False),
-            weights["blocks"])
-        q, k, v = model.qkv(p, hs, sel, d, quant)
+        p = _layer(layer_w, li)
+        q, k, v = model.qkv(p, hs, sel, d, li, quant)
         bk = rec_k[li].at[sel].set(k)
         bv = rec_v[li].at[sel].set(v)
-        hs = model.finish(p, hs, model.attend(q, sel, bk, bv, pos, d), d,
-                          quant)
+        hs = model.finish(p, hs, model.attend(q, sel, bk, bv, pos, d, li), d,
+                          li, quant)
         return hs, rec_k.at[li].set(bk), rec_v.at[li].set(bv)
 
-    hs, rec_k, rec_v = jax.lax.fori_loop(CHECK_LAYER + 1, base_k.shape[0],
-                                         layer, (h[sel], rec_k, rec_v))
+    carry = (h[sel], rec_k, rec_v)
+    if isinstance(layer_w, list):
+        for li in range(CHECK_LAYER + 1, len(layer_w)):
+            carry = layer(li, carry)
+    else:
+        carry = jax.lax.fori_loop(CHECK_LAYER + 1, base_k.shape[0], layer,
+                                  carry)
+    hs, rec_k, rec_v = carry
     return rec_k, rec_v, hs[-1]
 
 
@@ -160,21 +205,30 @@ def _extend(model, weights, tokens, rec_k, rec_v, *, d_items, quant):
     """KV [L, T, KV, hd] and final residual stream [T, D] of ``tokens``
     [T] placed right after the recovered prompt."""
     d = dict(d_items)
+    layer_w = model.blocks(weights)
     S, T = rec_k.shape[1], tokens.shape[0]
     pos = S + jnp.arange(T)
     kv_pos = jnp.arange(S + T)
 
     def layer(h, xs):
-        p, bk, bv = xs
-        q, k, v = model.qkv(p, h, pos, d, quant)
+        p, li, bk, bv = xs
+        q, k, v = model.qkv(p, h, pos, d, li, quant)
         kk = jnp.concatenate([bk, k])
         vv = jnp.concatenate([bv, v])
-        h = model.finish(p, h, model.attend(q, pos, kk, vv, kv_pos, d), d,
-                         quant)
+        h = model.finish(p, h, model.attend(q, pos, kk, vv, kv_pos, d, li), d,
+                         li, quant)
         return h, (k, v)
 
-    h, (k, v) = jax.lax.scan(layer, model.embed(weights, tokens, quant),
-                             (weights["blocks"], rec_k, rec_v))
+    h = model.embed(weights, tokens, quant)
+    if isinstance(layer_w, list):
+        ks, vs = [], []
+        for li, p in enumerate(layer_w):
+            h, (k, v) = layer(h, (p, li, rec_k[li], rec_v[li]))
+            ks.append(k)
+            vs.append(v)
+        return jnp.stack(ks), jnp.stack(vs), h
+    h, (k, v) = jax.lax.scan(
+        layer, h, (layer_w, jnp.arange(rec_k.shape[0]), rec_k, rec_v))
     return k, v, h
 
 

@@ -5,29 +5,35 @@
 The cell is looked up by name in ``BENCHMARK.json``; its configuration,
 traffic, per-layer readers and output-check limits are the files
 ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``,
-``bench/metrics/<metric>.py`` and ``bench/limits/<cell>.json``.
+``bench/metrics/<metric>.py`` and ``bench/limits/<cell>.json``, and its
+configuration's ``architecture`` names its reference and the adapter
+that configures the program, ``bench/reference/<architecture>.py`` and
+``bench/adapters/<architecture>.py``.
 
 A run needs one TPU chip per ``chips`` of the cell and fails, printing no
 result, where JAX finds fewer. Set-up (``setup_s``, from process start):
 
-1. A child process serves session 0 of this seed on a throw-away engine,
-   every round of it, and exits before this process touches JAX. Every
-   program the session uses lands in the persistent compilation cache
-   (``bench/.cache/jax``): the programs of each round's prompt length,
-   and those whose shapes follow from what the tokens make the program
-   do (how many blocks recovery recomputed and restore copies, how many
-   differ between a Master and its Mirrors), so that no program
-   compiles inside the window. On a checkout's first run it compiles
-   them all; later runs load most from the cache.
-2. The weights are drawn on the device from ``--seed``.
+1. The weights are drawn on the device from ``--seed``.
+2. A throw-away engine serves session 0 of this seed, every round of it.
+   The program builds each jitted program once per process and every
+   later engine of the process runs it, so this builds every program the
+   session uses: those of each round's prompt length, and those whose
+   shapes follow from what the tokens make the program do (how many
+   blocks recovery recomputed and restore copies, how many differ
+   between a Master and its Mirrors), so that no program of the first
+   session is traced, compiled or loaded inside the window. On a
+   checkout's first run they compile, into the persistent compilation
+   cache (``bench/.cache/jax``); later runs load them from there. The
+   memory metrics are read on this engine: ``bytes_in_use`` before its
+   round 0, and ``bytes_in_use`` and ``peak_bytes_in_use`` after its
+   round ``memory_round``, when the process has served nothing else.
 3. A fresh engine serves rounds 0 (recompute) and 1 (full family restore
    and collective recovery) of session 0.
 
 The window starts at round 2 and serves rounds back to back until
 ``--seconds`` have passed, then finishes the round in progress. A session
 that runs out of rounds is followed by a fresh engine on the next
-session's traffic. Resident memory is read after round ``memory_round``
-of the first session. After the window, the served tokens of a sample of
+session's traffic. After the window, the served tokens of a sample of
 its requests are compared with a float32 replay of the served semantics
 (``bench/correct.py``, ``bench/reference/tokendance.py``).
 
@@ -44,11 +50,9 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
-import subprocess  # noqa: E402
 import sys  # noqa: E402
 from dataclasses import dataclass  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -61,6 +65,7 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
+from bench.program import ADAPTERS, load_module  # noqa: E402
 from bench.traffic.generator import generate_session, load_traffic  # noqa: E402
 
 
@@ -81,16 +86,13 @@ class Cell:
     per_layer: list
     limits: dict
     reference: object
+    adapters: Path = ADAPTERS
 
 
-def _load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def load_cell(name: str) -> Cell:
+def load_cell(name: str, references: Path = BENCH / "reference",
+              adapters: Path = ADAPTERS) -> Cell:
+    """The cell ``name``, its architecture's reference and adapter looked
+    up in the directories ``references`` and ``adapters``."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -104,13 +106,13 @@ def load_cell(name: str) -> Cell:
     def mine(m):
         return "workloads" not in m or name in m["workloads"]
 
-    reference = _load_module(
-        BENCH / "reference" / f"{cfg['architecture']}.py",
+    reference = load_module(
+        Path(references) / f"{cfg['architecture']}.py",
         f"bench_reference_{cfg['architecture']}")
     return Cell(name, int(w["chips"]), cfg, traffic,
                 [m for m in bench["end_to_end"] if mine(m)],
                 [m for m in bench["per_layer"] if mine(m)], limits,
-                reference)
+                reference, Path(adapters))
 
 
 # --------------------------------------------------------------------------
@@ -158,6 +160,8 @@ class View:
     dims: dict
     device_kind: object
     reduced: object = None
+    #: the record of set-up's warm-up session
+    setup: object = None
 
     def _inside(self, t0, t1) -> bool:
         return self.window[0] <= t0 and t1 <= self.window[1]
@@ -170,23 +174,6 @@ class View:
 
     def window_batches(self):
         return [b for b in self.rec.batches if self.window[0] <= b.t_round]
-
-
-def warm_up(cell: Cell, seed: int, rehearsal: bool) -> None:
-    """Serve session 0 of ``seed`` on a throw-away engine."""
-    from bench.program import Server
-    from bench.record import Recorder
-
-    jax, _ = start_jax(cell, rehearsal)
-    t0 = time.perf_counter()
-    weights = cell.reference.make_weights(seed, cell.cfg)
-    sess = generate_session(cell.traffic, cell.cfg["vocab_size"], seed, 0)
-    srv = Server(cell.cfg, cell.traffic, weights, sess, Recorder(), 0)
-    while not srv.done:
-        srv.run_round()
-        log(f"warm-up: round {srv.round_idx - 1} done "
-            f"at {time.perf_counter() - t0:.1f}s")
-    jax.block_until_ready(weights)
 
 
 _LISTENING = []
@@ -221,12 +208,29 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     weights = jax.block_until_ready(cell.reference.make_weights(seed, cell.cfg))
     w_bytes = cell.reference.weight_bytes(weights)
 
-    def new_server(i):
+    def new_server(i, rec=rec):
         return Server(cell.cfg, tr, weights, generate_session(tr, V, seed, i),
-                      rec, i, sync_plan=trace)
+                      rec, i, trace=trace, adapters=cell.adapters)
 
-    srv = new_server(0)
+    t_warm = time.perf_counter()
+    setup = Recorder()
+    warm = new_server(0, setup)
     before = memory(jax, dev).get("bytes_in_use")
+    mem = {}
+    while not warm.done:
+        warm.run_round()
+        if warm.round_idx - 1 == tr["memory_round"]:
+            mem = memory(jax, dev)
+    warm.close()
+    del warm
+    log(f"bench: warm-up session served in "
+        f"{time.perf_counter() - t_warm:.3f}s; bytes in use {before} before "
+        f"round 0, {mem.get('bytes_in_use')} after round "
+        f"{tr['memory_round']}, peak {mem.get('peak_bytes_in_use')}")
+    for sp in setup.program_spans:
+        if sp.name.startswith("jit:"):
+            log(f"bench: warm-up round {sp.round} {sp.name} {sp.dur:.4f}s")
+    srv = new_server(0)
     for _ in range(2):
         srv.run_round()
     _barrier(jax)
@@ -241,7 +245,6 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
         opts.host_tracer_level = 2
         opts.enable_hlo_proto = False
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
-    mem = {}
     t0 = time.perf_counter()
     rec.open("window")
     sessions = 1
@@ -251,8 +254,6 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
             srv = new_server(sessions)
             sessions += 1
         srv.run_round()
-        if srv.index == 0 and srv.round_idx - 1 == tr["memory_round"]:
-            mem = memory(jax, dev)
         if time.perf_counter() - t0 >= seconds:
             break
     _barrier(jax)
@@ -264,7 +265,8 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
 
     view = View(rec, window, tr, cell.reference.dims(cell.cfg),
-                dev.device_kind if dev.platform == "tpu" else None)
+                dev.device_kind if dev.platform == "tpu" else None,
+                setup=setup)
     batches = view.window_batches()
     reqs = correct.finished_requests(batches)
     attempted = sum(b.n for b in batches)
@@ -317,9 +319,13 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
             log(f"bench: idle {secs:.4f}s in {name}")
         for name, secs in view.reduced.idle_gaps():
             log(f"bench: idle gap {secs:.4f}s in {name}")
+        recorded = sum(t0 <= s.t0 and s.t1 <= t1 for s in rec.program_spans)
+        log(f"bench: {len(view.reduced.program_spans)} program spans in the "
+            f"trace, {recorded} recorded in the window, "
+            f"{len(view.reduced.module_events)} programs run on device 0")
         for m in cell.per_layer:
-            mod = _load_module(BENCH / "metrics" / f"{m['name']}.py",
-                               "bench_metric_" + m["name"].replace(".", "_"))
+            mod = load_module(BENCH / "metrics" / f"{m['name']}.py",
+                              "bench_metric_" + m["name"].replace(".", "_"))
             v = mod.read(view)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
@@ -385,22 +391,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--warm-up", action="store_true",
-                    help=argparse.SUPPRESS)
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="run on whatever JAX finds (tests only)")
     args = ap.parse_args(argv)
     cell = load_cell(args.workload)
-    if args.warm_up:
-        warm_up(cell, args.seed, args.cpu_rehearsal)
-        return 0
-    cmd = [sys.executable, str(Path(__file__).resolve()), "--workload",
-           cell.name, "--seed", str(args.seed), "--seconds", "0",
-           "--warm-up"] + (["--cpu-rehearsal"] if args.cpu_rehearsal else [])
-    rc = subprocess.run(cmd, stdout=sys.stderr).returncode
-    if rc:
-        log(f"bench: warm-up pass failed ({rc})")
-        return rc
     out = measure(cell, args.seed, args.seconds, bool(args.trace),
                   args.cpu_rehearsal)
     print(json.dumps(out), flush=True)

@@ -4,12 +4,14 @@
 Its device planes (``/device:TPU:<i>``) carry one line of XLA operations
 (``XLA Ops``) and one of whole programs (``XLA Modules``); the host plane
 carries the benchmark's spans as ``bench:<name>`` annotations on the same
-clock. From these:
+clock, and the program's own spans as ``td:<name>`` annotations. From
+these:
 
 * busy intervals: the union of the operation intervals of each device;
 * the traced window: the ``bench:window`` span;
 * device time inside a set of spans, idle gaps labelled by the innermost
-  span they fall in, and the operations and programs that took most time.
+  span they fall in, and the operations and programs that took most time;
+* the program's spans, and device 0's programs one by one, as intervals.
 """
 from __future__ import annotations
 
@@ -21,7 +23,9 @@ from typing import Dict, List, Tuple
 
 from bench.record import SPAN_PREFIX
 
-DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
+DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
+#: the program's span annotations (``repro.serving.trace.SPAN_PREFIX``)
+PROGRAM_SPAN_PREFIX = "td:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -54,6 +58,10 @@ class Reduced:
     spans: List[Tuple[str, float, float]]  # host spans, prefix stripped
     ops: Dict[str, float] = field(default_factory=dict)      # name -> ns
     modules: Dict[str, float] = field(default_factory=dict)  # name -> ns
+    #: the program's host spans, prefix stripped
+    program_spans: List[Tuple[str, float, float]] = field(default_factory=list)
+    #: device 0's programs, each run as (name, start, end)
+    module_events: List[Tuple[str, float, float]] = field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -118,11 +126,12 @@ def _module_name(name: str) -> str:
 
 def reduce_xspace(data) -> Reduced:
     """Reduce a ``jax.profiler.ProfileData``."""
-    busy, spans = [], []
+    busy, spans, program_spans, module_events = [], [], [], []
     ops: Dict[str, float] = defaultdict(float)
     modules: Dict[str, float] = defaultdict(float)
     for plane in data.planes:
-        if DEVICE_PLANE.match(plane.name):
+        dev = DEVICE_PLANE.match(plane.name)
+        if dev:
             ivs = []
             for line in plane.lines:
                 if line.name == OPS_LINE:
@@ -131,14 +140,22 @@ def reduce_xspace(data) -> Reduced:
                         ops[ev.name] += ev.duration_ns
                 elif line.name == MODULES_LINE:
                     for ev in line.events:
-                        modules[_module_name(ev.name)] += ev.duration_ns
+                        name = _module_name(ev.name)
+                        modules[name] += ev.duration_ns
+                        if dev.group(2) == "0":
+                            module_events.append(
+                                (name, ev.start_ns,
+                                 ev.start_ns + ev.duration_ns))
             busy.append(union(ivs))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
+                    iv = (ev.start_ns, ev.start_ns + ev.duration_ns)
                     if ev.name.startswith(SPAN_PREFIX):
-                        spans.append((ev.name[len(SPAN_PREFIX):], ev.start_ns,
-                                      ev.start_ns + ev.duration_ns))
+                        spans.append((ev.name[len(SPAN_PREFIX):],) + iv)
+                    elif ev.name.startswith(PROGRAM_SPAN_PREFIX):
+                        program_spans.append(
+                            (ev.name[len(PROGRAM_SPAN_PREFIX):],) + iv)
     win = [(s, e) for n, s, e in spans if n == "window"]
     if win:
         window = (min(s for s, _ in win), max(e for _, e in win))
@@ -146,7 +163,9 @@ def reduce_xspace(data) -> Reduced:
         window = (min(s for _, s, _ in spans), max(e for _, _, e in spans))
     else:
         window = (0.0, 0.0)
-    return Reduced(window, busy, spans, dict(ops), dict(modules))
+    return Reduced(window, busy, spans, dict(ops), dict(modules),
+                   sorted(program_spans, key=lambda x: x[1]),
+                   sorted(module_events, key=lambda x: x[1]))
 
 
 def load(trace_dir: Path) -> Reduced:
